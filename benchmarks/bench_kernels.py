@@ -11,7 +11,12 @@ try:
     from benchmarks.common import block, row, timeit
 except ImportError:  # run as a script: benchmarks/ is sys.path[0]
     from common import block, row, timeit
-from repro.kernels import ops, ref
+from repro.kernels import decode_attention as _dec
+from repro.kernels import flash_attention as _fa
+from repro.kernels import ref
+from repro.kernels import rmsnorm as _rms
+from repro.kernels import rwkv6_scan as _rwkv
+from repro.kernels import ssd_scan as _ssd
 
 RNG = np.random.default_rng(0)
 
@@ -23,8 +28,8 @@ def ra(*s, scale=1.0):
 def main() -> None:
     # flash attention
     q, k, v = ra(1, 4, 256, 64), ra(1, 2, 256, 64), ra(1, 2, 256, 64)
-    f_kern = jax.jit(lambda q, k, v: ops.flash_attention(q, k, v, True, 0,
-                                                         128, 128))
+    f_kern = jax.jit(lambda q, k, v: _fa.flash_attention(q, k, v, True, 0,
+                                                         128, 128, True))
     f_ref = jax.jit(lambda q, k, v: ref.flash_attention_ref(q, k, v,
                                                             causal=True))
     err = float(jnp.max(jnp.abs(f_kern(q, k, v) - f_ref(q, k, v))))
@@ -34,7 +39,8 @@ def main() -> None:
     # decode attention
     q1, k1, v1 = ra(4, 8, 64), ra(4, 2, 1024, 64), ra(4, 2, 1024, 64)
     vl = jnp.asarray(1024, jnp.int32)
-    d_kern = jax.jit(lambda a, b, c: ops.decode_attention(a, b, c, vl))
+    d_kern = jax.jit(lambda a, b, c: _dec.decode_attention(
+        a, b, c, vl, interpret=True))
     d_ref = jax.jit(lambda a, b, c: ref.decode_attention_ref(a, b, c, vl))
     err = float(jnp.max(jnp.abs(d_kern(q1, k1, v1) - d_ref(q1, k1, v1))))
     us = timeit(lambda: block(d_ref(q1, k1, v1)), iters=10)
@@ -48,7 +54,7 @@ def main() -> None:
     # chunk 32: beyond ~32 steps the pairwise-decay exponent range
     # exceeds fp32 headroom at this decay scale (documented saturation
     # limit, DESIGN.md §7) -- tests/test_kernels.py sweeps chunks 16-32
-    kk = jax.jit(lambda *a: ops.rwkv6_wkv(*a, chunk=32)[0])
+    kk = jax.jit(lambda *a: _rwkv.rwkv6_wkv(*a, chunk=32, interpret=True)[0])
     rr = jax.jit(lambda *a: ref.rwkv6_wkv_ref(*a)[0])
     err = float(jnp.max(jnp.abs(kk(r, k2, v2, lw, u) - rr(r, k2, v2, lw, u))))
     us = timeit(lambda: block(rr(r, k2, v2, lw, u)), iters=3)
@@ -59,7 +65,7 @@ def main() -> None:
     dt = jnp.abs(ra(1, 4, 256, scale=.3)) + .1
     a = -jnp.abs(ra(1, 4, 256, scale=.3)) * dt
     b, c = ra(1, 256, 8, scale=.5), ra(1, 256, 8, scale=.5)
-    sk = jax.jit(lambda *t: ops.ssd_scan(*t, chunk=64)[0])
+    sk = jax.jit(lambda *t: _ssd.ssd_scan(*t, chunk=64, interpret=True)[0])
     sr = jax.jit(lambda *t: ref.ssd_ref(*t)[0])
     err = float(jnp.max(jnp.abs(sk(x, dt, a, b, c) - sr(x, dt, a, b, c))))
     us = timeit(lambda: block(sr(x, dt, a, b, c)), iters=3)
@@ -67,7 +73,7 @@ def main() -> None:
 
     # rmsnorm
     xx, g = ra(512, 512), ra(512, scale=.1)
-    nk = jax.jit(lambda a, b: ops.rmsnorm(a, b))
+    nk = jax.jit(lambda a, b: _rms.rmsnorm(a, b, interpret=True))
     nr = jax.jit(lambda a, b: ref.rmsnorm_ref(a, b))
     err = float(jnp.max(jnp.abs(nk(xx, g) - nr(xx, g))))
     us = timeit(lambda: block(nr(xx, g)), iters=10)

@@ -65,16 +65,19 @@ for name, ov in variants.items():
     jax.clear_caches()
 print("RESULT" + json.dumps(out))
 """
-    env = dict(os.environ, PYTHONPATH=SRC, TF_CPP_MIN_LOG_LEVEL="3")
+    # the child is a host-only dry run: pin it to the CPU, so it never
+    # reaches for an accelerator this process (or another) may hold
+    env = dict(os.environ, PYTHONPATH=SRC, TF_CPP_MIN_LOG_LEVEL="3",
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=900)
     payload = None
     for line in r.stdout.splitlines():
         if line.startswith("RESULT"):
             payload = json.loads(line[len("RESULT"):])
-    if payload is None:
-        row("fig21_placement/ERROR", 0.0, r.stderr[-200:].replace(",", ";"))
-        return
+    if r.returncode != 0 or payload is None:
+        raise RuntimeError(f"placement dry run failed (exit {r.returncode}):"
+                           f"\n{r.stderr[-2000:]}")
     for name, d in payload.items():
         term = d["coll_bytes"] / 50e9
         row(f"fig21_placement/{name}", term * 1e6,

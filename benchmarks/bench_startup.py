@@ -55,12 +55,12 @@ def main() -> None:
 
     # prewarmed: background compile overlaps 'current component running'
     key2 = key1 + "/next"
-    th = cc.prewarm(key2, _build_fn(384))
+    fut = cc.prewarm(key2, _build_fn(384))
     time.sleep(0.9)        # current component executes meanwhile
     t0 = time.perf_counter()
     cc.get_or_compile(key2, _build_fn(384))
     pre_ms = (time.perf_counter() - t0) * 1e3
-    th.join(timeout=10)
+    fut.result(timeout=10)     # re-raises a failed background compile
 
     row("fig23_startup/cold", cold_ms * 1e3, f"critical_path={cold_ms:.1f}ms")
     row("fig23_startup/warm_cache", warm_ms * 1e3,

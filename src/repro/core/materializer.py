@@ -40,15 +40,55 @@ GB = 1 << 30
 
 
 @dataclass(frozen=True)
+class ChipSpec:
+    """Published figures of one accelerator chip."""
+    hbm_bytes: int
+    peak_flops: float                      # bf16 FLOP/s
+    hbm_bw: float                          # HBM bytes/s
+    ici_bw: float                          # interconnect bytes/s per link
+
+
+#: Chip figures keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+#: 1,600 Gbit/s of chip-to-chip interconnect (four 50 GB/s links).
+CHIPS: Dict[str, ChipSpec] = {
+    "TPU v5 lite": ChipSpec(hbm_bytes=16 * GB, peak_flops=197e12,
+                            hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """The table entry for ``device_kind``; an unknown kind is an error,
+    never a default."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published chip figures for device kind "
+                         f"{device_kind!r}; add them to CHIPS") from None
+
+
+@dataclass(frozen=True)
 class MeshSpec:
     """Production mesh description (decoupled from jax device state)."""
     name: str
     shape: Tuple[int, ...]
     axes: Tuple[str, ...]
-    hbm_per_device: int = 16 * GB          # TPU v5e
-    peak_flops: float = 197e12             # bf16 / chip
-    hbm_bw: float = 819e9                  # bytes/s
-    ici_bw: float = 50e9                   # bytes/s/link
+    hbm_per_device: int
+    peak_flops: float                      # bf16 / chip
+    hbm_bw: float                          # bytes/s
+    ici_bw: float                          # bytes/s/link
+
+    @classmethod
+    def of_chip(cls, name: str, shape: Tuple[int, ...],
+                axes: Tuple[str, ...], device_kind: str, *,
+                hbm_per_device: Optional[int] = None) -> "MeshSpec":
+        """A mesh of ``device_kind`` chips; ``hbm_per_device`` overrides
+        the published capacity with what the runtime reports."""
+        chip = chip_spec(device_kind)
+        return cls(name, tuple(shape), tuple(axes),
+                   hbm_per_device=int(hbm_per_device or chip.hbm_bytes),
+                   peak_flops=chip.peak_flops, hbm_bw=chip.hbm_bw,
+                   ici_bw=chip.ici_bw)
 
     @property
     def num_devices(self) -> int:
@@ -65,8 +105,10 @@ class MeshSpec:
         return tuple(a for a in self.axes if a != "model")
 
 
-SINGLE_POD = MeshSpec("single_pod", (16, 16), ("data", "model"))
-MULTI_POD = MeshSpec("multi_pod", (2, 16, 16), ("pod", "data", "model"))
+SINGLE_POD = MeshSpec.of_chip("single_pod", (16, 16), ("data", "model"),
+                              "TPU v5 lite")
+MULTI_POD = MeshSpec.of_chip("multi_pod", (2, 16, 16),
+                             ("pod", "data", "model"), "TPU v5 lite")
 
 MESHES = {m.name: m for m in (SINGLE_POD, MULTI_POD)}
 

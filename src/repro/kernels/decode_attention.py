@@ -62,7 +62,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      valid_len: jax.Array, *, block_s: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """q: (B, H, D); k, v: (B, KVH, S, D); valid_len: () or (B,) int32.
 
     Returns (B, H, D).  Attends over positions [0, valid_len)."""

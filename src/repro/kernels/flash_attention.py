@@ -2,8 +2,7 @@
 
 TPU-native design (vs. a CUDA port):
   * Tiles are MXU/VMEM-shaped: (block_q x head_dim) / (block_k x head_dim)
-    blocks staged HBM->VMEM by BlockSpecs; dot_generals hit the 128x128 MXU
-    (ops.py pads odd head dims to multiples of 128 on real hardware).
+    blocks staged HBM->VMEM by BlockSpecs; dot_generals hit the 128x128 MXU.
   * GQA is folded into the BlockSpec index maps (KV block index = q_head //
     group): no materialized head expansion in HBM.
   * Online-softmax running state (m, l, acc) lives in VMEM scratch and
@@ -13,7 +12,8 @@ TPU-native design (vs. a CUDA port):
 
 Backward is the standard two-pass flash recipe: recompute p from the saved
 logsumexp; pass A accumulates dq over k-blocks, pass B accumulates (dk, dv)
-over q-blocks.  ref.py holds the jnp oracle; ops.py wires custom_vjp.
+over q-blocks.  ``flash_attention`` wires both through ``jax.custom_vjp``;
+ref.py holds the jnp oracle.
 """
 
 from __future__ import annotations
@@ -94,7 +94,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=0, block_q=128,
-                        block_k=128, interpret=True):
+                        block_k=128, interpret: bool):
     """q: (B, H, S, D); k, v: (B, KVH, S, D) -> (o, lse (B,H,S) fp32)."""
     b, h, s, d = q.shape
     kvh = k.shape[1]
@@ -216,7 +216,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
-                        block_q=128, block_k=128, interpret=True):
+                        block_q=128, block_k=128, interpret: bool):
     b, h, s, d = q.shape
     kvh = k.shape[1]
     g = h // kvh
@@ -287,3 +287,34 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
+# custom VJP: training differentiates through the kernel pair
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention(q, k, v, causal: bool, window: int, block_q: int,
+                    block_k: int, interpret: bool):
+    """q: (B, H, S, D); k, v: (B, KVH, S, D) -> (B, H, S, D)."""
+    o, _ = flash_attention_fwd(q, k, v, causal=causal, window=window,
+                               block_q=block_q, block_k=block_k,
+                               interpret=interpret)
+    return o
+
+
+def _vjp_fwd(q, k, v, causal, window, block_q, block_k, interpret):
+    o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                 block_q=block_q, block_k=block_k,
+                                 interpret=interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _vjp_bwd(causal, window, block_q, block_k, interpret, res, do):
+    q, k, v, o, lse = res
+    return flash_attention_bwd(q, k, v, o, lse, do, causal=causal,
+                               window=window, block_q=block_q,
+                               block_k=block_k, interpret=interpret)
+
+
+flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

@@ -1,96 +1,74 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public entry points for the Pallas kernels, and THE dispatch decision.
 
-Dispatch policy: on TPU backends the compiled kernels run natively; on CPU
-(this container) ``interpret=True`` executes the kernel bodies in Python
-for correctness validation.  ``flash_attention`` wires the fwd/bwd kernels
-through jax.custom_vjp so training uses the kernel gradient path.
+``use_compiled_kernels`` is the one place that chooses: on a TPU backend
+every wrapper runs its compiled Pallas kernel (``interpret=False``);
+elsewhere it runs the kernel's jnp oracle from ``ref.py`` (or
+``paged_attention_ref``).  No wrapper runs a kernel in interpret mode --
+the interpreter is a correctness tool that tests ask for explicitly by
+calling the kernel modules with ``interpret=True``.  The decision is made
+at trace time, so it follows the backend a program is compiled for.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import paged_attention as _paged
+from repro.kernels import ref
 from repro.kernels import rmsnorm as _rms
 from repro.kernels import rwkv6_scan as _rwkv
 from repro.kernels import ssd_scan as _ssd
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def use_compiled_kernels() -> bool:
+    """True where the compiled Pallas kernels run: a TPU backend."""
+    return jax.default_backend() == "tpu"
 
 
-# ---------------------------------------------------------------------------
-# flash attention with custom VJP
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128):
-    """q: (B, H, S, D); k, v: (B, KVH, S, D) -> (B, H, S, D)."""
-    o, _ = _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                   block_q=block_q, block_k=block_k,
-                                   interpret=_interpret())
-    return o
+    """q: (B, H, S, D); k, v: (B, KVH, S, D) -> (B, H, S, D).  On TPU the
+    kernel pair is differentiated through its custom VJP."""
+    if use_compiled_kernels():
+        return _fa.flash_attention(q, k, v, causal, window, block_q, block_k,
+                                   False)
+    return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
 
-
-def _fa_fwd(q, k, v, causal, window, block_q, block_k):
-    o, lse = _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                     block_q=block_q, block_k=block_k,
-                                     interpret=_interpret())
-    return o, (q, k, v, o, lse)
-
-
-def _fa_bwd(causal, window, block_q, block_k, res, do):
-    q, k, v, o, lse = res
-    dq, dk, dv = _fa.flash_attention_bwd(
-        q, k, v, o, lse, do, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_interpret())
-    return dq, dk, dv
-
-
-flash_attention.defvjp(_fa_fwd, _fa_bwd)
-
-
-def flash_attention_bshd(q, k, v, *, causal=True, window=0, block_q=128,
-                         block_k=128):
-    """(B, S, H, D)-layout convenience wrapper (model-layer layout)."""
-    o = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                        v.transpose(0, 2, 1, 3), causal, window,
-                        block_q, block_k)
-    return o.transpose(0, 2, 1, 3)
-
-
-# ---------------------------------------------------------------------------
-# decode attention / scans / norm (inference or fwd-only paths)
-# ---------------------------------------------------------------------------
 
 def decode_attention(q, k, v, valid_len, *, block_s: int = 512):
-    return _dec.decode_attention(q, k, v, valid_len, block_s=block_s,
-                                 interpret=_interpret())
+    if use_compiled_kernels():
+        return _dec.decode_attention(q, k, v, valid_len, block_s=block_s,
+                                     interpret=False)
+    return ref.decode_attention_ref(q, k, v, valid_len)
 
 
 def rwkv6_wkv(r, k, v, logw, u, *, chunk: int = 128):
-    return _rwkv.rwkv6_wkv(r, k, v, logw, u, chunk=chunk,
-                           interpret=_interpret())
+    if use_compiled_kernels():
+        return _rwkv.rwkv6_wkv(r, k, v, logw, u, chunk=chunk,
+                               interpret=False)
+    return ref.rwkv6_wkv_ref(r, k, v, logw, u)
 
 
 def ssd_scan(x, dt, a, b, c, *, chunk: int = 128):
-    return _ssd.ssd_scan(x, dt, a, b, c, chunk=chunk,
-                         interpret=_interpret())
+    if use_compiled_kernels():
+        return _ssd.ssd_scan(x, dt, a, b, c, chunk=chunk, interpret=False)
+    return ref.ssd_ref(x, dt, a, b, c)
 
 
 def rmsnorm(x, gain, *, eps: float = 1e-6, block_rows: int = 128):
-    return _rms.rmsnorm(x, gain, eps=eps, block_rows=block_rows,
-                        interpret=_interpret())
+    if use_compiled_kernels():
+        return _rms.rmsnorm(x, gain, eps=eps, block_rows=block_rows,
+                            interpret=False)
+    return ref.rmsnorm_ref(x, gain, eps=eps)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, valid_len, *,
                     window: int = 0, ring: bool = False):
-    return _paged.paged_attention(q, k_pages, v_pages, page_table, valid_len,
-                                  window=window, ring=ring,
-                                  interpret=_interpret())
+    if use_compiled_kernels():
+        return _paged.paged_attention(q, k_pages, v_pages, page_table,
+                                      valid_len, window=window, ring=ring,
+                                      interpret=False)
+    return _paged.paged_attention_ref(q, k_pages, v_pages, page_table,
+                                      valid_len, window=window, ring=ring)

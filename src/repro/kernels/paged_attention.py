@@ -111,7 +111,7 @@ def _paged_kernel(table_ref, q_ref, k_ref, v_ref, len_ref, o_ref,
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     page_table: jax.Array, valid_len: jax.Array, *,
                     window: int = 0, ring: bool = False,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (B, H, D); k/v_pages: (P, page, KV, D) pool; page_table:
     (B, max_pages) int32 (-1 padded); valid_len: (B,) total tokens.
 

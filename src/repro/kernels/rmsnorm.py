@@ -22,7 +22,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jax.Array, gain: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 128, interpret: bool = True) -> jax.Array:
+            block_rows: int = 128, interpret: bool) -> jax.Array:
     """x: (..., D); gain: (D,).  (1+gain) parameterization (see layers)."""
     orig_shape = x.shape
     d = orig_shape[-1]

@@ -70,7 +70,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, sout_ref, s_scr,
         sout_ref[0, 0] = s_new.astype(sout_ref.dtype)
 
 
-def rwkv6_wkv(r, k, v, logw, u, *, chunk: int = 128, interpret: bool = True
+def rwkv6_wkv(r, k, v, logw, u, *, chunk: int = 128, interpret: bool
               ) -> Tuple[jax.Array, jax.Array]:
     """r,k,v,logw: (B, H, S, hd); u: (H, hd).
 

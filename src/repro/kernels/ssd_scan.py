@@ -66,7 +66,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sout_ref, s_scr,
         sout_ref[0, 0] = s_new.astype(sout_ref.dtype)
 
 
-def ssd_scan(x, dt, a, b, c, *, chunk: int = 128, interpret: bool = True
+def ssd_scan(x, dt, a, b, c, *, chunk: int = 128, interpret: bool
              ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, H, S, P); dt, a: (B, H, S); b, c: (B, S, N).
 

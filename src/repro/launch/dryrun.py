@@ -205,12 +205,8 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, plan: Plan, mesh,
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() across jax versions: older releases return
-    a one-element list of dicts, newer ones the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 def memory_footprint(compiled) -> Dict[str, int]:

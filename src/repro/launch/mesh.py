@@ -11,19 +11,12 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax has Auto-only meshes
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.core.materializer import MESHES, MeshSpec
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -49,3 +42,15 @@ def make_local_mesh(axes: Tuple[str, ...] = ("data", "model"),
     if shape is None:
         shape = (n,) + (1,) * (len(axes) - 1)
     return _make_mesh(shape, axes)
+
+
+def attached_mesh_spec(name: str = "attached") -> MeshSpec:
+    """Describe the devices this process is attached to: their count and
+    kind (figures from the ``CHIPS`` table, which raises on an unknown
+    kind), with HBM per device taken from ``memory_stats()["bytes_limit"]``
+    where the backend reports it."""
+    devices = jax.devices()
+    stats = devices[0].memory_stats() or {}
+    return MeshSpec.of_chip(name, (len(devices), 1), ("data", "model"),
+                            devices[0].device_kind,
+                            hbm_per_device=stats.get("bytes_limit"))

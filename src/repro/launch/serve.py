@@ -15,6 +15,7 @@ import numpy as np
 from repro import obs
 from repro.configs import get_config
 from repro.core import profiles as prof
+from repro.core.compile_cache import configure_persistent_cache
 from repro.core.history import HistoryStore
 from repro.core.materializer import MESHES
 from repro.runtime import Application, Cluster, JaxExecutor, NullExecutor
@@ -77,6 +78,7 @@ def main():
                     help="record latency histograms and print the "
                          "Prometheus text exposition at the end")
     args = ap.parse_args()
+    configure_persistent_cache()
     if args.backend != "dense" and not args.reduced:
         ap.error("--backend needs --reduced: the default arm serves through "
                  "the NullExecutor (no model, no kernel path)")

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.core.compile_cache import configure_persistent_cache
 from repro.core.history import HistoryStore
 from repro.core.materializer import MESHES
 from repro.runtime import Application, Cluster, JaxExecutor
@@ -32,6 +33,7 @@ def main():
                     help="CPU smoke scale (same code path)")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    configure_persistent_cache()
 
     history = HistoryStore("artifacts/history")
     app = Application.train(args.arch, shape=args.shape,

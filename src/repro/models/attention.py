@@ -22,7 +22,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.layers import Spec, apply_rope, rms_norm, rms_norm_spec
 
@@ -268,10 +267,10 @@ def seqshard_cache_update(cache: jax.Array, new: jax.Array, slot: jax.Array,
         val = jnp.where(in_range, new_l.astype(cache_l.dtype), cur)
         return jax.lax.dynamic_update_slice_in_dim(cache_l, val, loc, 2)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(cache_spec, new_spec, P()),
-        out_specs=cache_spec)(cache, new, slot)
+        out_specs=cache_spec, check_vma=False)(cache, new, slot)
 
 
 def gqa_decode_sdpa(q: jax.Array, k: jax.Array, v: jax.Array,

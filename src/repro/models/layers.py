@@ -8,6 +8,7 @@ planner share one source of truth with the compute code.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -41,6 +42,16 @@ def is_spec(x) -> bool:
     return isinstance(x, Spec)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal_leaf(key: jax.Array, shape: Tuple[int, ...], std: float,
+                 dtype) -> jax.Array:
+    """One weight drawn in f32, scaled and cast in ONE program, so the f32
+    draw stays inside the fusion.  Drawn op by op, each stacked weight
+    held its f32 draw and its scaled copy in device memory, and dispatch
+    running ahead kept several of them in flight at once."""
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
 def init_from_specs(rng: jax.Array, specs, dtype=jnp.bfloat16):
     """Materialize a params pytree from a spec pytree."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
@@ -54,8 +65,8 @@ def init_from_specs(rng: jax.Array, specs, dtype=jnp.bfloat16):
             # norm gains default to ones
             out.append(jnp.ones(spec.shape, dtype))
         else:
-            out.append((jax.random.normal(key, spec.shape, jnp.float32)
-                        * spec.std).astype(dtype))
+            out.append(_normal_leaf(key, spec.shape, float(spec.std),
+                                    jnp.dtype(dtype)))
     return jax.tree.unflatten(treedef, out)
 
 

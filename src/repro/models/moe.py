@@ -26,7 +26,6 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.layers import Spec, gated_mlp, gated_mlp_specs
 
@@ -212,12 +211,14 @@ def moe_block(p: Params, x: jax.Array, cfg: ModelConfig,
             auxl = jax.lax.pmean(auxl, tuple(mesh.axis_names))
             return yl, auxl
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(tok_spec, None), P(None, None),
                       P(model_axis, None, None), P(model_axis, None, None),
                       P(model_axis, None, None)),
-            out_specs=(P(tok_spec, None), P()))(flat, p["router"], p["we_gate"], p["we_up"], p["we_down"])
+            out_specs=(P(tok_spec, None), P()),
+            check_vma=False)(flat, p["router"], p["we_gate"], p["we_up"],
+                             p["we_down"])
     else:
         mesh, model_axis, batch_axes = shard_ctx
         bspec = (batch_axes if len(batch_axes) > 1 else
@@ -232,12 +233,14 @@ def moe_block(p: Params, x: jax.Array, cfg: ModelConfig,
             auxl = jax.lax.pmean(auxl, tuple(mesh.axis_names))
             return yl, auxl
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(bspec, None), P(None, None),
                       P(model_axis, None, None), P(model_axis, None, None),
                       P(model_axis, None, None)),
-            out_specs=(P(bspec, None), P()))(flat, p["router"], p["we_gate"], p["we_up"], p["we_down"])
+            out_specs=(P(bspec, None), P()),
+            check_vma=False)(flat, p["router"], p["we_gate"], p["we_up"],
+                             p["we_down"])
 
     if m.num_shared_experts > 0:
         gate = jax.nn.sigmoid(

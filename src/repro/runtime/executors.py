@@ -269,6 +269,12 @@ class JaxExecutor(Executor):
         use_rings = opts.swa_rings
         pool = self.build_pool(
             handle, view_name=replica_view_name(app.name, idx))
+        prim = handle.exec_state.get("runner")
+        # replicas serve one model: alias the primary's weights so a
+        # replica costs compute slots, not a second params copy (nor the
+        # transient one initializing it would take)
+        params = (prim.params if idx > 0 and prim is not None
+                  and prim.backend == backend else None)
         try:
             kv_store = None
             if (backend == "paged"
@@ -317,7 +323,8 @@ class JaxExecutor(Executor):
                                   pool_pages=pool.physical_pages,
                                   use_rings=use_rings, kv_store=kv_store,
                                   prefix_cache=prefix_cache,
-                                  chunk_pages=opts.chunk_pages or 4)
+                                  chunk_pages=opts.chunk_pages or 4,
+                                  params=params)
         except Exception:
             # the pool view is already registered on the pod: an orphan
             # would dilute every tenant's fair share forever (close also
@@ -326,11 +333,6 @@ class JaxExecutor(Executor):
             if close is not None:
                 close()
             raise
-        prim = handle.exec_state.get("runner")
-        if idx > 0 and prim is not None and prim.backend == runner.backend:
-            # replicas serve one model: alias the primary's weights so a
-            # replica costs compute slots, not a second params copy
-            runner.params = prim.params
         eng = ServingEngine(pool, max_batch=max_batch, runner=runner,
                             history=handle.cluster.history)
         if idx == 0:

@@ -15,8 +15,9 @@ Two implementations:
   across the batch (the historical approximation).
 * :class:`PagedRunner` -- KV lives in the ``(pool_pages, PAGE_SIZE, KV,
   hd)`` layout granted page-by-page by the engine's pool; decode attends
-  through :func:`repro.kernels.ops.paged_attention` (Pallas kernel on
-  TPU, interpreted ref path on CPU) driven by each request's page table.
+  through :func:`repro.kernels.ops.paged_attention` (the compiled Pallas
+  kernel on TPU, its jnp oracle elsewhere -- ``ops`` decides) driven by
+  each request's page table.
   Positions and valid lengths are exact per request, so co-batched
   requests of different lengths decode correctly -- and the KV footprint
   is the pages the sizing policy granted, not ``max_batch * cache_len``.
@@ -58,7 +59,6 @@ from repro.checkpoint.checkpointer import _from_saved, _to_savable
 from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
 from repro.obs import trace as obs_trace
 from repro.kernels import ops
-from repro.kernels.paged_attention import paged_attention_ref
 from repro.models import ImplConfig, build_model
 from repro.models import attention as attn
 from repro.models import layers as L
@@ -187,6 +187,13 @@ class ModelRunner:
         if toks is not None:
             req.output_tokens = toks
 
+    def close(self) -> None:
+        """Drop this runner's device references; it is not used again.  A
+        runner sits in reference cycles (with its engine, and with its
+        jitted bound methods), so without this a retired replica keeps
+        the weights alive until Python's cyclic collector runs."""
+        self.params = None
+
     # -- idle parking (repro.autoscale.parking) ------------------------------
     @staticmethod
     def _tree_to_host(tree) -> Tuple[list, Any]:
@@ -259,13 +266,14 @@ class DenseRunner(ModelRunner):
     backend = "dense"
 
     def __init__(self, cfg: ModelConfig, *, seed: int = 0, max_batch: int = 4,
-                 cache_len: int = 256):
+                 cache_len: int = 256, params=None):
         super().__init__()
         self.cfg = cfg
         self.max_batch = max_batch
         self.cache_len = cache_len
         self.model = build_model(cfg, ImplConfig(remat="none"))
-        self.params = self.model.init_params(jax.random.PRNGKey(seed))
+        self.params = (params if params is not None
+                       else self.model.init_params(jax.random.PRNGKey(seed)))
 
         # compile attribution: the tracer instants fire at XLA trace
         # time (Python, shapes are static ints), so each marks one
@@ -345,6 +353,10 @@ class DenseRunner(ModelRunner):
         super().finish(req)
         self.slots.pop(req.req_id, None)
 
+    def close(self) -> None:
+        super().close()
+        self.cache = None
+
     def park(self, drained):
         """The dense cache is one contiguous tree: snapshot every leaf to
         host and drop the device copy."""
@@ -391,7 +403,7 @@ class PagedRunner(ModelRunner):
                  pool_pages: int = 128, max_batch: int = 4,
                  use_rings: bool = True,
                  kv_store: Optional[KVArrayStore] = None,
-                 prefix_cache=None, chunk_pages: int = 4):
+                 prefix_cache=None, chunk_pages: int = 4, params=None):
         super().__init__()
         if (any(k not in self.SUPPORTED_KINDS for k in cfg.pattern)
                 or cfg.rope_theta <= 0 or cfg.is_encdec
@@ -413,7 +425,8 @@ class PagedRunner(ModelRunner):
         self.prefix = prefix_cache
         self.chunk_pages = max(int(chunk_pages), 1)
         self.model = build_model(cfg, ImplConfig(remat="none"))
-        self.params = self.model.init_params(jax.random.PRNGKey(seed))
+        self.params = (params if params is not None
+                       else self.model.init_params(jax.random.PRNGKey(seed)))
         nb, pat = cfg.num_blocks, len(cfg.pattern)
         self.num_layers = nb * pat
         self.pool_pages = pool_pages
@@ -428,12 +441,6 @@ class PagedRunner(ModelRunner):
         self.store = kv_store if kv_store is not None else KVArrayStore(key)
         self.store.ensure_arrays()      # a parked-dropped store revives
         self.page_shape = self.store.page_shape
-        # the Pallas kernel natively on TPU; its jnp oracle elsewhere (the
-        # interpreted kernel is validated against the oracle in
-        # tests/test_kernels.py, and is ~60x slower than the oracle on CPU)
-        self._paged_attn = (ops.paged_attention
-                            if jax.default_backend() == "tpu"
-                            else paged_attention_ref)
         # compile-count observability: incremented at TRACE time, so each
         # attribute counts XLA compiles, not calls (regression-tested)
         self.decode_traces = 0
@@ -871,10 +878,9 @@ class PagedRunner(ModelRunner):
                 vp = new_v[layer].at[phys, off].set(
                     v[:, 0].astype(KV_DTYPE))
                 new_k[layer], new_v[layer] = kp, vp
-                o = self._paged_attn(q[:, 0], kp, vp,
-                                     table_l if ring else table_g, vlen,
-                                     window=w if kind == ATTN_LOCAL else 0,
-                                     ring=ring)
+                o = ops.paged_attention(
+                    q[:, 0], kp, vp, table_l if ring else table_g, vlen,
+                    window=w if kind == ATTN_LOCAL else 0, ring=ring)
                 return o[:, None]
 
             x = self._block_forward(bp, x, positions, mix)
@@ -882,9 +888,11 @@ class PagedRunner(ModelRunner):
         logits = L.unembed(params["embed"], x, cfg.logit_softcap)
         return jnp.argmax(logits[:, -1], -1), new_k, new_v
 
-    def decode(self, running: List[Request]) -> None:
-        if not running:
-            return
+    def decode_inputs(self, running: List[Request]) -> Tuple[jax.Array, ...]:
+        """The per-step arguments of ``_decode_fn`` for ``running``, after
+        ``params`` and before the page arrays: tokens, positions, write
+        pages (global, ring), write offsets, page tables (global, ring)
+        and valid lengths, padded to ``max_batch`` lanes."""
         b = self.max_batch
         assert len(running) <= b, f"{len(running)} running > max_batch {b}"
         ring = self.groups.ring_pages if self.use_rings else 1
@@ -942,10 +950,14 @@ class PagedRunner(ModelRunner):
             if self.use_rings:
                 phys_l[i] = l_phys[i][(p // PAGE_SIZE) % ring]
                 table_l[i, :len(l_phys[i])] = l_phys[i]
+        return tuple(jnp.asarray(a) for a in (toks, positions, phys_g, phys_l,
+                                              offs, table_g, table_l, vlen))
+
+    def decode(self, running: List[Request]) -> None:
+        if not running:
+            return
         nxt, self.store.k_pages, self.store.v_pages = self._decode(
-            self.params, jnp.asarray(toks), jnp.asarray(positions),
-            jnp.asarray(phys_g), jnp.asarray(phys_l), jnp.asarray(offs),
-            jnp.asarray(table_g), jnp.asarray(table_l), jnp.asarray(vlen),
+            self.params, *self.decode_inputs(running),
             self.store.k_pages, self.store.v_pages)
         # zenlint: ignore[ZL004] -- THE one batched device->host fetch
         # per decode step (all lanes' tokens in one transfer); every
@@ -1062,14 +1074,16 @@ def build_runner(backend: str, cfg: ModelConfig, *, seed: int = 0,
                  max_batch: int = 4, cache_len: int = 256,
                  pool_pages: int = 128, use_rings: bool = True,
                  kv_store: Optional[KVArrayStore] = None,
-                 prefix_cache=None, chunk_pages: int = 4) -> ModelRunner:
+                 prefix_cache=None, chunk_pages: int = 4,
+                 params=None) -> ModelRunner:
     """Factory keyed by ``Application.options['backend']``.  ``kv_store``
     aliases the paged backend onto the pod's shared device arrays;
     ``prefix_cache`` attaches the pod's global prefix cache (paged only:
     the dense backend has no page identity to share, so asking for a
     cache there is REJECTED rather than silently dropped -- a benchmark
     must never compare a cached arm against one that quietly never
-    cached)."""
+    cached).  ``params`` reuses an existing weight tree (a replica of the
+    same model) instead of initializing one."""
     if backend == "dense":
         if prefix_cache is not None:
             raise ValueError(
@@ -1077,11 +1091,11 @@ def build_runner(backend: str, cfg: ModelConfig, *, seed: int = 0,
                 "dense KV cache has no shareable page identity; use "
                 "backend='paged' or drop the option")
         return DenseRunner(cfg, seed=seed, max_batch=max_batch,
-                           cache_len=cache_len)
+                           cache_len=cache_len, params=params)
     if backend == "paged":
         return PagedRunner(cfg, seed=seed, pool_pages=pool_pages,
                            max_batch=max_batch, use_rings=use_rings,
                            kv_store=kv_store, prefix_cache=prefix_cache,
-                           chunk_pages=chunk_pages)
+                           chunk_pages=chunk_pages, params=params)
     raise ValueError(f"unknown serving backend {backend!r} "
                      "(expected 'dense' or 'paged')")

@@ -136,6 +136,8 @@ class ReplicaSet:
             setattr(self.retired, f, getattr(self.retired, f)
                     + getattr(victim.engine.stats, f))
         victim.engine.shutdown()        # frees nothing (drained); closes view
+        if victim.runner is not None:
+            victim.runner.close()
         self.replicas_removed += 1
         self._rebalance()
         t = obs_trace.TRACER
@@ -268,6 +270,8 @@ class ReplicaSet:
         # close drops the shared device arrays exactly once
         for r in sorted(self.replicas, key=lambda r: -r.idx):
             r.engine.shutdown()
+            if r.runner is not None:
+                r.runner.close()
         self.replicas.clear()
 
 

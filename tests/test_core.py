@@ -2,13 +2,16 @@
 graph, history, scheduler, compile cache."""
 
 import dataclasses
+import os
 
+import jax
 import pytest
 
 from repro.configs import ALL_ARCHS, SHAPES, get_config, shape_applicable
 from repro.core.graph import build_resource_graph
 from repro.core.history import DecayedHistogram, HistoryStore
-from repro.core.materializer import (MESHES, MULTI_POD, SINGLE_POD, GB,
+from repro.core.materializer import (CHIPS, MESHES, MULTI_POD, SINGLE_POD,
+                                     GB, MeshSpec, chip_spec,
                                      estimate_bytes_per_device, escalate,
                                      materialize)
 from repro.core.compile_cache import CompileCache, plan_layout_key
@@ -21,6 +24,24 @@ from repro.models import layers as L
 # ---------------------------------------------------------------------------
 # materializer
 # ---------------------------------------------------------------------------
+
+def test_chip_table_is_the_only_source_of_figures():
+    """Production meshes take their figures from the chip table; a kind
+    the table does not list raises instead of getting a default."""
+    v5e = CHIPS["TPU v5 lite"]
+    for mesh in (SINGLE_POD, MULTI_POD):
+        assert (mesh.hbm_per_device, mesh.peak_flops, mesh.hbm_bw,
+                mesh.ici_bw) == (v5e.hbm_bytes, v5e.peak_flops, v5e.hbm_bw,
+                                 v5e.ici_bw)
+    with pytest.raises(ValueError, match="cpu"):
+        chip_spec("cpu")
+    with pytest.raises(TypeError):
+        MeshSpec("bare", (1, 1), ("data", "model"))     # no silent figures
+    one = MeshSpec.of_chip("one", (1, 1), ("data", "model"), "TPU v5 lite",
+                           hbm_per_device=15 * GB)
+    assert one.num_devices == 1 and one.hbm_per_device == 15 * GB
+    assert one.peak_flops == v5e.peak_flops
+
 
 @pytest.mark.parametrize("mesh", ["single_pod", "multi_pod"])
 @pytest.mark.parametrize("arch", ALL_ARCHS)
@@ -250,6 +271,72 @@ def test_compile_cache_single_flight_and_hits():
     assert cc.get_or_compile("k1", build) == "exe"
     assert len(calls) == 1
     assert cc.stats["hits"] == 1
+
+
+def test_compile_cache_failed_build_releases_waiters():
+    """A build that raises passes its error on, and a waiter on the same
+    key is released (it retries as the new owner) instead of blocking."""
+    import threading
+
+    cc = CompileCache()
+    started, release = threading.Event(), threading.Event()
+
+    def failing():
+        started.set()
+        release.wait(5)
+        raise RuntimeError("compile failed")
+
+    errors, results = [], []
+
+    def owner():
+        try:
+            cc.get_or_compile("k", failing)
+        except RuntimeError as e:
+            errors.append(str(e))
+
+    t_owner = threading.Thread(target=owner)
+    t_owner.start()
+    assert started.wait(5)
+    t_wait = threading.Thread(
+        target=lambda: results.append(cc.get_or_compile("k", lambda: "exe")))
+    t_wait.start()
+    release.set()
+    t_owner.join(5)
+    t_wait.join(5)
+    assert not t_owner.is_alive() and not t_wait.is_alive()
+    assert errors == ["compile failed"]
+    assert results == ["exe"] and cc.contains("k")
+
+
+def test_compile_cache_prewarm_reports_failure():
+    cc = CompileCache()
+
+    def failing():
+        raise ValueError("bad layout")
+
+    with pytest.raises(ValueError, match="bad layout"):
+        cc.prewarm("k", failing).result(timeout=5)
+    assert cc.prewarm("k", lambda: "exe").result(timeout=5) == "exe"
+    assert cc.stats["prewarmed"] == 1
+
+
+def test_persistent_cache_placement(monkeypatch):
+    """The environment variable wins and nothing is set in code; unset,
+    the cache goes to the fixed git-ignored directory of the checkout."""
+    from repro.core import compile_cache as ccm
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert ccm.configure_persistent_cache() == "/somewhere/else"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = ccm.configure_persistent_cache()
+    assert updates == [("jax_compilation_cache_dir", path)]
+    assert path == ccm.REPO_CACHE_DIR
+    assert os.path.basename(path) == ".jax_cache"
+    assert os.path.dirname(path) == os.path.abspath(
+        os.path.join(os.path.dirname(__file__), ".."))
 
 
 def test_plan_layout_key_stable():

@@ -106,7 +106,7 @@ opt_state = opt.init_opt_state(params)
 batch = {"tokens": jax.random.randint(rng, (8, 16), 0, cfg.vocab_size),
          "labels": jax.random.randint(rng, (8, 16), 0, cfg.vocab_size)}
 
-spec = MeshSpec("test", (2, 4), ("data", "model"))
+spec = MeshSpec.of_chip("test", (2, 4), ("data", "model"), "TPU v5 lite")
 plan = Plan("t", "train_4k", spec, batch_axes=("data",), tp=True,
             zero=True, remat="none", microbatch=1)
 step = make_train_step(model, plan)
@@ -146,8 +146,8 @@ params = model.init_params(jax.random.PRNGKey(0))
 
 mesh_a = _make_mesh((2, 4), ("data", "model"))
 mesh_b = _make_mesh((4, 2), ("data", "model"))
-spec_a = MeshSpec("a", (2, 4), ("data", "model"))
-spec_b = MeshSpec("b", (4, 2), ("data", "model"))
+spec_a = MeshSpec.of_chip("a", (2, 4), ("data", "model"), "TPU v5 lite")
+spec_b = MeshSpec.of_chip("b", (4, 2), ("data", "model"), "TPU v5 lite")
 plan_a = Plan("t", "train_4k", spec_a, batch_axes=("data",), tp=True)
 plan_b = Plan("t", "train_4k", spec_b, batch_axes=("data",), tp=True)
 specs = model.param_specs()

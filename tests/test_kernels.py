@@ -1,12 +1,20 @@
 """Per-kernel shape/dtype sweeps: pallas_call(interpret=True) vs ref.py
-oracles (deliverable c: per-kernel allclose)."""
+oracles (deliverable c: per-kernel allclose).  The kernels are called
+through their modules with ``interpret=True`` stated: ``ops`` would run
+the oracles themselves on this backend."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import decode_attention as _dec
+from repro.kernels import flash_attention as _fa
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as _paged
+from repro.kernels import rmsnorm as _rms
+from repro.kernels import rwkv6_scan as _rwkv
+from repro.kernels import ssd_scan as _ssd
 
 RNG = np.random.default_rng(7)
 
@@ -34,7 +42,7 @@ TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
 def test_flash_attention_fwd(b, h, kvh, s, d, causal, window, dtype):
     q, k, v = ra(b, h, s, d, dtype=dtype), ra(b, kvh, s, d, dtype=dtype), \
         ra(b, kvh, s, d, dtype=dtype)
-    o = ops.flash_attention(q, k, v, causal, window, 64, 64)
+    o = _fa.flash_attention(q, k, v, causal, window, 64, 64, True)
     o_ref = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), **TOL[dtype])
@@ -46,7 +54,8 @@ def test_flash_attention_grads(causal, window):
     q, k, v = ra(b, h, s, d), ra(b, kvh, s, d), ra(b, kvh, s, d)
 
     def f(q, k, v):
-        return (ops.flash_attention(q, k, v, causal, window, 64, 64) ** 2).sum()
+        return (_fa.flash_attention(q, k, v, causal, window, 64, 64, True)
+                ** 2).sum()
 
     def fr(q, k, v):
         o = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
@@ -65,8 +74,9 @@ def test_flash_matches_model_chunked_sdpa():
     b, h, kvh, s, d = 1, 4, 2, 256, 32
     q, k, v = ra(b, s, h, d), ra(b, s, kvh, d), ra(b, s, kvh, d)
     o_model = sdpa(q, k, v, causal=True, impl="chunked", chunk=64)
-    o_kernel = ops.flash_attention_bshd(q, k, v, causal=True,
-                                        block_q=64, block_k=64)
+    o_kernel = _fa.flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), True, 0, 64, 64, True).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(o_model, np.float32),
                                np.asarray(o_kernel, np.float32),
                                rtol=2e-5, atol=2e-5)
@@ -83,7 +93,7 @@ def test_decode_attention(b, h, kvh, s, d, dtype):
     q = ra(b, h, d, dtype=dtype)
     k, v = ra(b, kvh, s, d, dtype=dtype), ra(b, kvh, s, d, dtype=dtype)
     vlen = jnp.asarray(RNG.integers(1, s, size=(b,)), jnp.int32)
-    o = ops.decode_attention(q, k, v, vlen, block_s=64)
+    o = _dec.decode_attention(q, k, v, vlen, block_s=64, interpret=True)
     o_ref = ref.decode_attention_ref(q, k, v, vlen)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), **TOL[dtype])
@@ -99,7 +109,7 @@ def test_rwkv6_wkv(b, h, s, hd, chunk):
     r, k, v = (ra(b, h, s, hd, scale=0.5) for _ in range(3))
     logw = -jnp.exp(ra(b, h, s, hd, scale=0.5) - 1.0)
     u = ra(h, hd, scale=0.3)
-    o, st = ops.rwkv6_wkv(r, k, v, logw, u, chunk=chunk)
+    o, st = _rwkv.rwkv6_wkv(r, k, v, logw, u, chunk=chunk, interpret=True)
     o_ref, st_ref = ref.rwkv6_wkv_ref(r, k, v, logw, u)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
@@ -131,7 +141,7 @@ def test_ssd_scan(b, h, s, p, n, chunk):
     dt = jnp.abs(ra(b, h, s, scale=0.3)) + 0.1
     a = -jnp.abs(ra(b, h, s, scale=0.3)) * dt
     bmat, cmat = ra(b, s, n, scale=0.5), ra(b, s, n, scale=0.5)
-    y, st = ops.ssd_scan(x, dt, a, bmat, cmat, chunk=chunk)
+    y, st = _ssd.ssd_scan(x, dt, a, bmat, cmat, chunk=chunk, interpret=True)
     y_ref, st_ref = ref.ssd_ref(x, dt, a, bmat, cmat)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
@@ -167,7 +177,7 @@ def test_ssd_model_chunked_matches_oracle():
 def test_rmsnorm(rows, d, dtype):
     x = ra(rows, d, dtype=dtype)
     g = ra(d, scale=0.1)
-    o = ops.rmsnorm(x, g)
+    o = _rms.rmsnorm(x, g, interpret=True)
     o_ref = ref.rmsnorm_ref(x, g)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), **TOL[dtype])
@@ -193,7 +203,7 @@ def test_paged_attention(b, h, kvh, d, pool, page, maxp):
     vlen = jnp.asarray([(int((table[i] >= 0).sum())) * page
                         - int(RNG.integers(0, page)) for i in range(b)],
                        jnp.int32)
-    o = paged_attention(q, kp, vp, table, vlen)
+    o = paged_attention(q, kp, vp, table, vlen, interpret=True)
     o_ref = paged_attention_ref(q, kp, vp, table, vlen)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
@@ -241,12 +251,12 @@ def test_paged_matches_contiguous_decode():
     q = ra(b, h, d)
     k, v = ra(b, kvh, s, d), ra(b, kvh, s, d)
     vlen = jnp.asarray([s - 7, s // 2], jnp.int32)
-    dense = ops.decode_attention(q, k, v, vlen, block_s=page)
+    dense = _dec.decode_attention(q, k, v, vlen, block_s=page, interpret=True)
     # build a per-request page pool from the contiguous cache
     kp = k.transpose(0, 2, 1, 3).reshape(b * npg, page, kvh, d)
     vp = v.transpose(0, 2, 1, 3).reshape(b * npg, page, kvh, d)
     table = jnp.arange(b * npg, dtype=jnp.int32).reshape(b, npg)
-    paged = ops.paged_attention(q, kp, vp, table, vlen)
+    paged = _paged.paged_attention(q, kp, vp, table, vlen, interpret=True)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                rtol=2e-5, atol=2e-5)
 
@@ -278,7 +288,7 @@ def test_paged_attention_ring_window(pos_last):
     args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(table), jnp.asarray([vlen]))
     o_ref = paged_attention_ref(*args, window=window, ring=True)
-    o_krn = paged_attention(*args, window=window, ring=True)
+    o_krn = paged_attention(*args, window=window, ring=True, interpret=True)
     # dense reference over the last `window` tokens
     lo = max(0, vlen - window)
     k = np.repeat(keys[lo:vlen], h // kvh, axis=1)
@@ -291,3 +301,24 @@ def test_paged_attention_ring_window(pos_last):
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(o_krn)[0], o_dense,
                                rtol=2e-5, atol=2e-5)
+
+
+def test_ops_runs_the_oracles_off_tpu():
+    """Off TPU every ``ops`` wrapper is its jnp oracle (exactly), so no
+    serving path reaches the interpreter; on TPU ``use_compiled_kernels``
+    sends them to the compiled kernels (tests/test_tpu_compile.py)."""
+    assert not ops.use_compiled_kernels()
+    b, h, kvh, d, pool, page = 2, 4, 2, 32, 6, 16
+    q = ra(b, h, d)
+    kp, vp = ra(pool, page, kvh, d), ra(pool, page, kvh, d)
+    table = jnp.asarray([[3, 1, -1], [0, 5, 2]], jnp.int32)
+    vlen = jnp.asarray([20, 40], jnp.int32)
+    got = ops.paged_attention(q, kp, vp, table, vlen)
+    want = _paged.paged_attention_ref(q, kp, vp, table, vlen)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    text = jax.jit(lambda *a: ops.paged_attention(*a)).lower(
+        q, kp, vp, table, vlen).as_text()
+    assert "pallas" not in text and "tpu_custom_call" not in text
+    x, g = ra(8, 64), ra(64, scale=0.1)
+    np.testing.assert_array_equal(np.asarray(ops.rmsnorm(x, g)),
+                                  np.asarray(ref.rmsnorm_ref(x, g)))

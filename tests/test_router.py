@@ -307,3 +307,55 @@ def test_stats_view_aggregates_replicas():
     h.remove_replica()
     assert view.cumulative()["completed"] == 9
     h.release()
+
+
+@pytest.mark.parametrize("backend", ["paged", "dense"])
+def test_replicas_share_the_primary_weights(backend, monkeypatch):
+    """A replica of the same model reuses the primary's weight tree; it
+    never initializes a second copy first (on a chip that transient copy
+    is a second full set of weights plus its f32 temporaries)."""
+    from repro.models.model import Model
+    inits = []
+    real = Model.init_params
+    monkeypatch.setattr(Model, "init_params",
+                        lambda self, rng: inits.append(1) or real(self, rng))
+    cluster = Cluster(pods=1, history=HistoryStore(),
+                      executor=JaxExecutor(seed=0), pool_pages=64)
+    h = _serve(cluster, f"weights-{backend}", backend=backend, max_batch=2,
+               replicas=2)
+    h.add_replica()
+    runners = [r.runner for r in h.replica_set.replicas]
+    assert len(runners) == 3 and len(inits) == 1
+    assert all(r.params is runners[0].params for r in runners)
+    h.release()
+
+
+def test_park_frees_retired_replica_weights_without_gc():
+    """Parking a two-replica app releases its weights at once: the replica
+    folded away before the drain drops its reference instead of keeping
+    the tree alive in a reference cycle until the cyclic collector runs
+    (on the chip, unpark then re-uploaded beside the stale copy)."""
+    import gc
+    import weakref
+
+    import jax
+    cluster = Cluster(pods=1, history=HistoryStore(),
+                      executor=JaxExecutor(seed=0), pool_pages=64)
+    h = _serve(cluster, "park-free", backend="paged", max_batch=2,
+               replicas=2)
+    for r in _reqs(4):
+        h.submit_request(r)
+    h.step()
+    h.step()
+    refs = [weakref.ref(x) for x in jax.tree.leaves(h.runner.params)]
+    gc.collect()
+    gc.disable()
+    try:
+        h.park()
+        alive = sum(r() is not None for r in refs)
+    finally:
+        gc.enable()
+    assert alive == 0, f"{alive} weight arrays outlived park"
+    h.submit_request(Request("late", PAGE_SIZE - 4, 2))     # unparks
+    assert h.run(max_steps=1000)["completed"] == 5
+    h.release()
