@@ -1,0 +1,132 @@
+"""Find what a cell is made of, by name, from files alone.
+
+A cell of ``BENCHMARK.json`` names a configuration and a traffic mix;
+the benchmark's per-layer metrics are named there too.  Each lives in a
+file of its own under the benchmark directory, so a later change adds a
+cell, a mix or a metric by adding files and entries, never by editing
+code:
+
+* ``configs/<config>.json`` -- the model as it is run (Hugging Face
+  ``config.json`` keys) and the serve options of the deployment;
+* ``traffic/<mix>.json`` -- the parameters of one traffic mix, whose
+  ``kind`` names the generator module ``traffic/<kind>.py``;
+* ``metrics/<metric>.py`` -- the reader of one per-layer metric, a
+  function ``read(record)`` that returns a number or None.
+
+Nothing here imports JAX or the program.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Optional
+
+#: the benchmark's own directory (configs/, traffic/, metrics/ live here)
+HERE = os.path.dirname(os.path.abspath(__file__))
+#: the checkout's root, where BENCHMARK.json lives
+ROOT = os.path.dirname(os.path.dirname(HERE))
+
+
+@dataclass
+class Cell:
+    """One entry of ``workloads`` with everything it names, loaded."""
+
+    name: str
+    chips: int
+    config: Dict[str, Any]
+    traffic: Dict[str, Any]
+    end_to_end: List[Dict[str, Any]]
+    per_layer: List[Dict[str, Any]]
+
+
+def load_json(path: str) -> Dict[str, Any]:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _applies(metric: Dict[str, Any], cell: str) -> bool:
+    return cell in metric.get("workloads", [cell])
+
+
+def load_cell(name: str, *, root: str = ROOT,
+              bench_dir: Optional[str] = None) -> Cell:
+    """The cell ``name`` of ``<root>/BENCHMARK.json``, with its
+    configuration and traffic files read from ``bench_dir`` (the
+    benchmark's directory unless given) and its metrics filtered to
+    those the cell reports."""
+    bench_dir = bench_dir or HERE
+    spec = load_json(os.path.join(root, "BENCHMARK.json"))
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json; known: "
+                       f"{sorted(cells)}")
+    w = cells[name]
+    configs = {c["name"]: c for c in spec["configs"]}
+    cfg_entry = configs[w["config"]]
+    config = load_json(os.path.join(root, cfg_entry["file"]))
+    traffic = load_json(os.path.join(bench_dir, "traffic",
+                                     w["traffic"] + ".json"))
+    return Cell(name=name, chips=int(w["chips"]), config=config,
+                traffic=traffic,
+                end_to_end=[m for m in spec["end_to_end"]
+                            if _applies(m, name)],
+                per_layer=[m for m in spec["per_layer"]
+                           if _applies(m, name)])
+
+
+def _load_module(path: str, mod_name: str):
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    if spec is None or spec.loader is None:
+        raise ImportError(f"cannot load {path}")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def generator(kind: str, *, bench_dir: Optional[str] = None):
+    """The traffic generator module ``traffic/<kind>.py``."""
+    bench_dir = bench_dir or HERE
+    return _load_module(os.path.join(bench_dir, "traffic", kind + ".py"),
+                        f"chipbench_traffic_{kind}")
+
+
+def metric_reader(name: str, *, bench_dir: Optional[str] = None
+                  ) -> Callable[[Dict[str, Any]], Optional[float]]:
+    """``read(record)`` of ``metrics/<name>.py``."""
+    bench_dir = bench_dir or HERE
+    mod = _load_module(os.path.join(bench_dir, "metrics", name + ".py"),
+                       "chipbench_metric_" + name.replace(".", "_")
+                       .replace("-", "_"))
+    return mod.read
+
+
+def model_config_kwargs(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``repro.configs.base.ModelConfig`` keyword arguments for a
+    configuration file written in Hugging Face ``config.json`` keys.
+    Only decoder-only, RoPE, SiLU-gated, RMS-normed stacks of full
+    attention are expressible; anything else is refused here rather than
+    run as something it is not."""
+    act = config.get("hidden_act", "silu")
+    if act != "silu":
+        raise ValueError(f"{config['name']}: hidden_act {act!r} is not the "
+                         "program's gated SiLU MLP")
+    d = int(config["hidden_size"])
+    heads = int(config["num_attention_heads"])
+    return dict(
+        name=config["name"],
+        family="dense",
+        num_layers=int(config["num_hidden_layers"]),
+        d_model=d,
+        num_heads=heads,
+        num_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=int(config.get("head_dim") or d // heads),
+        d_ff=int(config["intermediate_size"]),
+        vocab_size=int(config["vocab_size"]),
+        rope_theta=float(config["rope_theta"]),
+        norm_eps=float(config["rms_norm_eps"]),
+        tie_embeddings=bool(config.get("tie_word_embeddings", False)),
+        max_context=int(config["max_position_embeddings"]),
+    )
