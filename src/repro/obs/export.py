@@ -27,7 +27,8 @@ from .trace import Event, Tracer
 
 #: stable pid assignment per category so lanes group deterministically
 CAT_PIDS = {"request": 1, "engine": 2, "pool": 3, "compile": 4,
-            "autoscale": 5, "scheduler": 6}
+            "autoscale": 5, "scheduler": 6, "runner": 7, "host": 8,
+            "obs": 9}
 _OTHER_PID = 99
 
 

@@ -39,13 +39,20 @@ lane), and ``args`` is a small dict of host scalars (or None).
 
 Timebase: ``time.perf_counter()`` everywhere -- the same clock the
 engine stamps ``Request.submitted_at`` with, so trace timestamps and
-engine latencies compose exactly (see ``runtime.cluster`` train steps,
-normalized in this PR).
+engine latencies compose exactly (see ``runtime.cluster`` train steps).
+A profiler trace (``jax.profiler``) runs on a clock of its own;
+:meth:`Tracer.anchor` puts a mark on both clocks, so that two anchors,
+one at each end of a profiler session, map the ring onto the device
+trace (``docs/observability.md``, "Clock anchors").
+
+While a tracer is enabled it also records Python's garbage collector:
+one ``host``/``gc`` span per collection.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import time
 from typing import Deque, Dict, List, Optional, Tuple
 
@@ -62,6 +69,9 @@ TRACER: Optional["Tracer"] = None
 
 DEFAULT_CAPACITY = 1 << 16
 
+#: the name of the profiler annotation :meth:`Tracer.anchor` places
+CLOCK_ANNOTATION = "repro.obs.clock"
+
 
 class Tracer:
     """Bounded ring buffer of typed trace events (monotonic timestamps)."""
@@ -71,6 +81,7 @@ class Tracer:
         self.events: Deque[Event] = collections.deque(maxlen=self.capacity)
         self.dropped = 0
         self.t0 = time.perf_counter()
+        self._gc_start = 0.0
 
     # -- emission (the hot-path API: guard-and-append only) ------------------
     def instant(self, cat: str, name: str, scope: Optional[str] = None,
@@ -91,6 +102,35 @@ class Tracer:
         if len(ev) == self.capacity:
             self.dropped += 1
         ev.append((t_start, t_end - t_start, "X", cat, name, scope, args))
+
+    def anchor(self) -> None:
+        """Mark this instant on both clocks: enter and exit one
+        ``jax.profiler.TraceAnnotation`` named :data:`CLOCK_ANNOTATION`,
+        reading ``perf_counter`` just before entering and just after
+        exiting, and record one ``obs``/``clock`` instant with those two
+        readings (``t_in``, ``t_out``).  Whoever starts a profiler
+        session calls it right after ``start_trace`` and right before
+        ``stop_trace``; with no session running the annotation goes
+        nowhere and the instant is still recorded."""
+        from jax.profiler import TraceAnnotation
+        t_in = time.perf_counter()
+        with TraceAnnotation(CLOCK_ANNOTATION):
+            pass
+        t_out = time.perf_counter()
+        ev = self.events
+        if len(ev) == self.capacity:
+            self.dropped += 1
+        ev.append((t_in, 0.0, "i", "obs", "clock", None,
+                   {"t_in": t_in, "t_out": t_out}))
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        """``gc.callbacks`` hook: one ``host``/``gc`` span per collection."""
+        if phase == "start":
+            self._gc_start = time.perf_counter()
+        else:
+            self.span("host", "gc", self._gc_start, time.perf_counter(),
+                      None, {"generation": info["generation"],
+                             "collected": info["collected"]})
 
     # -- introspection -------------------------------------------------------
     def __len__(self) -> int:
@@ -115,19 +155,25 @@ class Tracer:
 
 
 def enable(capacity: int = DEFAULT_CAPACITY) -> Tracer:
-    """Install (and return) a fresh process-wide tracer.  Idempotent in
-    spirit: a second call replaces the ring (the old events are the
-    caller's to keep via ``snapshot()`` first)."""
+    """Install (and return) a fresh process-wide tracer, with its
+    garbage-collector hook.  Idempotent in spirit: a second call
+    replaces the ring (the old events are the caller's to keep via
+    ``snapshot()`` first)."""
     global TRACER
+    disable()
     TRACER = Tracer(capacity)
+    gc.callbacks.append(TRACER._on_gc)
     return TRACER
 
 
 def disable() -> Optional[Tracer]:
-    """Remove the process-wide tracer; returns it (with its events) so a
-    caller can still export what was captured."""
+    """Remove the process-wide tracer and its garbage-collector hook;
+    returns it (with its events) so a caller can still export what was
+    captured."""
     global TRACER
     t, TRACER = TRACER, None
+    if t is not None and t._on_gc in gc.callbacks:
+        gc.callbacks.remove(t._on_gc)
     return t
 
 

@@ -41,6 +41,7 @@ from repro.core.materializer import (MESHES, SINGLE_POD, MeshSpec, Plan,
 from repro.core.scheduler import GlobalScheduler, Job, PodState
 from repro.core.sizing import SizingSolution, solve_init_step
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.runtime.application import Application
 from repro.runtime.executors import Executor, NullExecutor
 from repro.serving.kv_cache import Request
@@ -188,10 +189,19 @@ class AppHandle:
             self.metrics.append(m)
             return m
         rset = self.replica_set
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
         if rset is not None and rset.router is not None:
             alive = rset.router.step_app(self.app.name)
         else:
             alive = self.engine.step()
+        if t is not None:
+            # the pages in use, at the step's end, of the pool the engine
+            # draws from (the pod's pool behind a view)
+            pool = getattr(self.engine.pool, "shared", self.engine.pool)
+            t.span("engine", "step", t0, time.perf_counter(), self.app.name,
+                   {"pages_used": pool.num_pages - len(pool.free),
+                    "pool_pages": pool.num_pages})
         return {"alive": alive, "stats": self.engine.stats}
 
     def run(self, steps: Optional[int] = None, *,

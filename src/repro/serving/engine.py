@@ -134,6 +134,7 @@ class ServingEngine:
         attach = getattr(self.runner, "prefix_attach", None)
         t = obs_trace.TRACER
         m = obs_metrics.METRICS
+        t_pass = time.perf_counter() if t is not None else 0.0
         while self.queue and len(self.running) < self.max_batch:
             req = self.queue[0]
             if not self.pool.admissible(req):
@@ -171,6 +172,9 @@ class ServingEngine:
                 if m is not None:
                     m.histogram("repro_queue_wait_seconds",
                                 app=self._obs_app).observe(wait)
+        if t is not None:
+            t.span("engine", "admit_pass", t_pass, time.perf_counter(),
+                   self._obs_app, {"admitted": len(admitted)})
         return admitted
 
     def preempt(self, victim: Request) -> None:
@@ -266,10 +270,15 @@ class ServingEngine:
         # `req in self.running` condition skips requests preempted by an
         # earlier reclaim in this pass -- growing one would grant pages to
         # a request whose pages were just released (page leak).
+        if t is not None:
+            t_grow, pre0 = time.perf_counter(), self.stats.preempted
         for req in list(self.running):
             while req in self.running and not self.pool.grow(req, horizon=1):
                 if not self._reclaim():
                     break
+        if t is not None:
+            t.span("engine", "grow", t_grow, time.perf_counter(),
+                   self._obs_app, {"preempted": self.stats.preempted - pre0})
 
         if self.step_fns is not None:
             _, decode_fn = self.step_fns
@@ -298,6 +307,8 @@ class ServingEngine:
                 m.histogram("repro_batch_occupancy",
                             obs_metrics.OCCUPANCY_BOUNDS,
                             app=self._obs_app).observe(len(self.running))
+        if t is not None:
+            t_ret, done0 = time.perf_counter(), self.stats.completed
         for req in list(self.running):
             req.generated += 1
             self.stats.tokens_generated += 1
@@ -313,6 +324,9 @@ class ServingEngine:
                 if t is not None:
                     t.instant("request", "finish", req.req_id,
                               {"tokens": req.generated})
+        if t is not None:
+            t.span("engine", "retire", t_ret, time.perf_counter(),
+                   self._obs_app, {"finished": self.stats.completed - done0})
         self.stats.decode_steps += 1
         s = zensan.SAN
         if s is not None:

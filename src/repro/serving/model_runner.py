@@ -47,6 +47,7 @@ outputs nondeterministic across runs.
 
 from __future__ import annotations
 
+import time
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -588,10 +589,7 @@ class PagedRunner(ModelRunner):
             if self.prefix is not None:
                 self._prefix_insert(req)
             return
-        toks = prompt_for(req, cfg.vocab_size)
-        pad = n_pg * PAGE_SIZE - req.prompt_len
-        if pad:
-            toks = jnp.pad(toks, ((0, 0), (0, pad)))
+        toks = self._upload_prompt(req, n_pg)
         if req.pages:
             g_ids = np.asarray(self._phys(req.pages[:n_pg]), np.int32)
         else:                               # pure-local stack: unused
@@ -611,9 +609,27 @@ class PagedRunner(ModelRunner):
             jnp.asarray(g_ids), jnp.asarray(l_ids), jnp.asarray(l_src),
             self.store.k_pages, self.store.v_pages)
         self.prefill_pages_computed += n_pg
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
         # zenlint: ignore[ZL004] -- first-token extraction: once per
         # request at prefill, the designed sync point (see DenseRunner).
         self.generated[req.req_id] = [int(nxt)]
+        if t is not None:
+            t.span("runner", "first_token_fetch", t0, time.perf_counter(),
+                   req.req_id)
+
+    def _upload_prompt(self, req: Request, n_pg: int) -> jax.Array:
+        """The prompt's tokens on the device, padded to ``n_pg`` pages."""
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
+        toks = prompt_for(req, self.cfg.vocab_size)
+        pad = n_pg * PAGE_SIZE - req.prompt_len
+        if pad:
+            toks = jnp.pad(toks, ((0, 0), (0, pad)))
+        if t is not None:
+            t.span("runner", "prompt_upload", t0, time.perf_counter(),
+                   req.req_id, {"tokens": n_pg * PAGE_SIZE})
+        return toks
 
     # -- chunked / suffix-only prefill (pure-global stacks) ------------------
     def _chunk_fn(self, params, toks, lead, base, last, g_ids, cow_src,
@@ -706,12 +722,8 @@ class PagedRunner(ModelRunner):
         ``chunk_pages`` -- warm and cold runs of the same prompt see
         IDENTICAL chunk boundaries past the cached region, so their
         attention math (and tokens) agree exactly."""
-        cfg = self.cfg
-        toks = prompt_for(req, cfg.vocab_size)
         total_pg = -(-req.prompt_len // PAGE_SIZE)
-        pad = total_pg * PAGE_SIZE - req.prompt_len
-        if pad:
-            toks = jnp.pad(toks, ((0, 0), (0, pad)))
+        toks = self._upload_prompt(req, total_pg)
         cached = req.cached_len
         pages_all = list(req.shared_pages) + self._phys(req.pages)
         assert len(pages_all) >= total_pg, \
@@ -720,6 +732,7 @@ class PagedRunner(ModelRunner):
         nxt = None
         tr = obs_trace.TRACER
         while p < total_pg:
+            tc = time.perf_counter() if tr is not None else 0.0
             n_pg = min(self.chunk_pages - p % self.chunk_pages,
                        total_pg - p)
             s0 = p * PAGE_SIZE
@@ -740,13 +753,18 @@ class PagedRunner(ModelRunner):
                 self.store.k_pages, self.store.v_pages)
             self.prefill_pages_computed += n_pg
             if tr is not None:
-                tr.instant("request", "prefill_chunk", req.req_id,
-                           {"start_page": p, "pages": n_pg, "lead": lead})
+                tr.span("request", "prefill_chunk", tc, time.perf_counter(),
+                        req.req_id,
+                        {"start_page": p, "pages": n_pg, "lead": lead})
             p += n_pg
         if self.prefix is not None and cached % PAGE_SIZE:
             # partial-page hit: the fused lead copy above IS the COW
             self.prefix.stats["cow_copies"] += 1
+        tf = time.perf_counter() if tr is not None else 0.0
         self.generated[req.req_id] = [int(nxt)]
+        if tr is not None:
+            tr.span("runner", "first_token_fetch", tf, time.perf_counter(),
+                    req.req_id)
 
     # -- prefix-cache lifecycle ----------------------------------------------
     def _host_prompt(self, req: Request) -> Tuple[int, ...]:
@@ -769,18 +787,19 @@ class PagedRunner(ModelRunner):
         admission fails."""
         if self.prefix is None or req.prefix_nodes is not None:
             return
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
         m = self.prefix.pin(self._host_prompt(req),
                             max_len=req.prompt_len - 1)
         req.prefix_nodes = m.nodes
         req.shared_pages = list(m.phys_pages)
         req.cached_len = m.cached_len
         req.cow_src_page = m.cow_src
-        t = obs_trace.TRACER
         if t is not None:
-            t.instant("request", "prefix_pin", req.req_id,
-                      {"cached_len": m.cached_len,
-                       "shared_pages": len(m.phys_pages),
-                       "cow": m.cow_src is not None})
+            t.span("request", "prefix_pin", t0, time.perf_counter(),
+                   req.req_id, {"cached_len": m.cached_len,
+                                "shared_pages": len(m.phys_pages),
+                                "cow": m.cow_src is not None})
 
     def _prefix_insert(self, req: Request) -> None:
         """Post-prefill donation: move the prompt's freshly computed full
@@ -791,6 +810,8 @@ class PagedRunner(ModelRunner):
         -- another request inserted the same prefix this tick -- adopts
         nothing: probe_new sizes the donation at 0 and this request just
         keeps its private copies."""
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
         cache = self.prefix
         toks = self._host_prompt(req)
         n_full = req.prompt_len // PAGE_SIZE
@@ -821,11 +842,10 @@ class PagedRunner(ModelRunner):
             created = cache.insert(toks, n_att, phys,
                                    partial_page=partial_phys)
             req.prefix_nodes = (req.prefix_nodes or []) + created
-            t = obs_trace.TRACER
             if t is not None:
-                t.instant("request", "prefix_insert", req.req_id,
-                          {"donated": len(phys),
-                           "partial": partial_phys is not None})
+                t.span("request", "prefix_insert", t0, time.perf_counter(),
+                       req.req_id, {"donated": len(phys),
+                                    "partial": partial_phys is not None})
 
     def prefix_reattach(self, req: Request) -> bool:
         """Unpark: re-pin the shared prefix chain a parked request was
@@ -893,6 +913,8 @@ class PagedRunner(ModelRunner):
         ``params`` and before the page arrays: tokens, positions, write
         pages (global, ring), write offsets, page tables (global, ring)
         and valid lengths, padded to ``max_batch`` lanes."""
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
         b = self.max_batch
         assert len(running) <= b, f"{len(running)} running > max_batch {b}"
         ring = self.groups.ring_pages if self.use_rings else 1
@@ -950,19 +972,33 @@ class PagedRunner(ModelRunner):
             if self.use_rings:
                 phys_l[i] = l_phys[i][(p // PAGE_SIZE) % ring]
                 table_l[i, :len(l_phys[i])] = l_phys[i]
-        return tuple(jnp.asarray(a) for a in (toks, positions, phys_g, phys_l,
-                                              offs, table_g, table_l, vlen))
+        out = tuple(jnp.asarray(a) for a in (toks, positions, phys_g, phys_l,
+                                             offs, table_g, table_l, vlen))
+        if t is not None:
+            t.span("runner", "decode_inputs", t0, time.perf_counter(), None,
+                   {"batch": len(running), "table_pages": maxp_b})
+        return out
 
     def decode(self, running: List[Request]) -> None:
         if not running:
             return
+        # the dispatch span holds the decode_inputs span (host tables and
+        # their transfer); its own time is the jitted call's enqueue
+        t = obs_trace.TRACER
+        t0 = time.perf_counter() if t is not None else 0.0
         nxt, self.store.k_pages, self.store.v_pages = self._decode(
             self.params, *self.decode_inputs(running),
             self.store.k_pages, self.store.v_pages)
+        if t is not None:
+            t.span("runner", "decode_dispatch", t0, time.perf_counter())
+            t0 = time.perf_counter()
         # zenlint: ignore[ZL004] -- THE one batched device->host fetch
         # per decode step (all lanes' tokens in one transfer); every
         # other read below indexes this host copy.
         nxt = np.asarray(nxt)
+        if t is not None:
+            t.span("runner", "token_fetch", t0, time.perf_counter(), None,
+                   {"batch": len(running)})
         for i, req in enumerate(running):
             self.generated[req.req_id].append(int(nxt[i]))
 

@@ -191,7 +191,7 @@ class PrefixCache:
         t = obs_trace.TRACER
         if t is not None:
             # the cache's own view of the lookup (the request-scoped
-            # prefix_pin instant is the attach-side receipt)
+            # prefix_pin span is the attach-side receipt)
             t.instant("pool", "prefix_lookup", None,
                       {"hit": cached_len > 0, "cached_len": cached_len,
                        "full_pages": len(full_pages),

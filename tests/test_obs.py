@@ -5,9 +5,11 @@ critically -- exact request-lifecycle reconstruction: the events an
 instrumented run captures must agree with the engine's own counters,
 and the whole plane must be a no-op when disabled."""
 
+import gc
 import json
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -17,7 +19,7 @@ from repro.core.history import HistoryStore
 from repro.obs.metrics import (LATENCY_BOUNDS, OCCUPANCY_BOUNDS, Histogram,
                                hist_delta, hist_merge)
 from repro.obs.summary import pctl, request_lifecycles, summarize
-from repro.runtime import (Application, Cluster, NullExecutor,
+from repro.runtime import (Application, Cluster, JaxExecutor, NullExecutor,
                            ServeOptions)
 from repro.serving.engine import ServingEngine
 from repro.serving.kv_cache import PAGE_SIZE, PagePool, Request
@@ -172,6 +174,17 @@ def test_trace_matches_engine_counters():
     # every admit records a non-negative queue wait
     assert all(e[6]["queue_wait_s"] >= 0.0
                for e in t.by_name("admit", "request"))
+    # the step's own spans: one admission pass a step, one grow and one
+    # retire span a decode step, their args summing to the counters; a
+    # null engine has no runner, so no runner spans
+    passes = t.by_name("admit_pass", "engine")
+    assert len(passes) >= s.decode_steps
+    assert sum(e[6]["admitted"] for e in passes) == s.admitted
+    assert len(t.by_name("grow", "engine")) == s.decode_steps
+    retire = t.by_name("retire", "engine")
+    assert len(retire) == s.decode_steps
+    assert sum(e[6]["finished"] for e in retire) == s.completed
+    assert not [e for e in t.snapshot() if e[3] == "runner"]
 
 
 def test_pool_events_and_preempt():
@@ -194,6 +207,9 @@ def test_pool_events_and_preempt():
         denials = t.by_name("denial", "pool")
         assert denials and denials[0][6]["cause"] in ("quota", "physical")
     assert len(t.by_name("preempt", "request")) == eng.stats.preempted
+    # every preemption happens in a grow pass, which counts it
+    assert sum(e[6]["preempted"] for e in t.by_name("grow", "engine")) \
+        == eng.stats.preempted
     h.release()
 
 
@@ -239,6 +255,169 @@ def test_park_unpark_and_autoscale_events():
     h.run(max_steps=50_000)
     h.release()
     assert t.by_name("job_finish", "scheduler")
+
+
+# ---------------------------------------------------------------------------
+# the serving step's own spans on the paged path
+# ---------------------------------------------------------------------------
+
+def _toks(n, seed):
+    return tuple((seed * 7919 + i * 31) % 211 for i in range(n))
+
+
+def _paged_app(prefix):
+    cluster = Cluster(pods=1, history=HistoryStore(),
+                      executor=JaxExecutor(seed=0), pool_pages=64)
+    return cluster.submit(Application.serve(
+        "tinyllama-1.1b", reduced=True, name="obs-paged",
+        serve=ServeOptions(max_batch=4, backend="paged", policy="fixed",
+                           prefix_cache=prefix)))
+
+
+def _serve_paged(h, n=6):
+    """``n`` requests over one shared two-page prefix, through
+    ``AppHandle.submit_request`` / ``AppHandle.step``; returns the number
+    of steps."""
+    for i in range(n):
+        toks = _toks(2 * PAGE_SIZE + 10, 1) + _toks(40 + 9 * i, 100 + i)
+        h.submit_request(Request(f"p{i}", len(toks), 5, prompt_tokens=toks))
+    steps = 0
+    while h.step()["alive"]:
+        steps += 1
+    return steps + 1
+
+
+def _inside(child, parents):
+    a, b = child[0], child[0] + child[1]
+    return any(p[0] <= a and b <= p[0] + p[1] for p in parents)
+
+
+@pytest.mark.parametrize("prefix", [False, True],
+                         ids=["whole-prompt", "prefix-chunked"])
+def test_paged_step_spans_match_engine_counters(prefix):
+    h = _paged_app(prefix)
+    t = obs.enable()
+    steps = _serve_paged(h)
+    s = h.engine.stats
+    # the events that were there keep their counts
+    assert len(t.by_name("submit", "request")) == 6
+    assert len(t.by_name("admit", "request")) == s.admitted == 6
+    assert len(t.by_name("finish", "request")) == s.completed == 6
+    assert len(t.by_name("first_token", "request")) == s.ttft_count
+    assert len(t.by_name("decode_step", "engine")) == s.decode_steps
+    assert len(t.by_name("prefill", "request")) == s.prefills
+    # one device wait per decode step and per prefill
+    fetches = t.by_name("token_fetch", "runner")
+    assert len(fetches) == s.decode_steps
+    assert sum(e[6]["batch"] for e in fetches) == s.tokens_generated
+    assert len(t.by_name("first_token_fetch", "runner")) == s.prefills
+    assert len(t.by_name("decode_dispatch", "runner")) == s.decode_steps
+    prompts = t.by_name("prompt_upload", "runner")
+    assert len(prompts) == s.prefills
+    assert all(e[6]["tokens"] % PAGE_SIZE == 0 for e in prompts)
+    uploads = t.by_name("decode_inputs", "runner")
+    assert len(uploads) == s.decode_steps
+    assert [e[6]["batch"] for e in uploads] == [e[6]["batch"]
+                                                 for e in fetches]
+    assert all(e[6]["table_pages"] & (e[6]["table_pages"] - 1) == 0
+               for e in uploads), "table widths are powers of two"
+    # one engine/step span per AppHandle.step, with the pool's counters
+    step_spans = t.by_name("step", "engine")
+    assert len(step_spans) == steps
+    assert all(e[5] == "obs-paged" and e[2] == "X" for e in step_spans)
+    assert all(0 <= e[6]["pages_used"] <= e[6]["pool_pages"] == 64
+               for e in step_spans)
+    assert all(set(e[6]) == {"pages_used", "pool_pages"} for e in step_spans)
+    # every span below the step lies inside one engine/step span
+    below = [e for e in t.snapshot()
+             if e[2] == "X" and (e[3] == "runner" or e[3] == "engine"
+                                 and e[4] != "step")]
+    assert below and all(_inside(e, step_spans) for e in below)
+    # the engine's spans share its lane
+    lanes = {e[5] for e in below if e[3] == "engine"}
+    assert lanes == {t.by_name("decode_step", "engine")[0][5]}
+    chunks = t.by_name("prefill_chunk", "request")
+    pins = t.by_name("prefix_pin", "request")
+    if prefix:
+        # the instants that became spans keep their count and args
+        assert len(pins) == len(t.by_name("prefix_lookup", "pool")) == 6
+        assert sum(e[6]["pages"] for e in chunks) \
+            == h.runner.prefill_pages_computed
+        assert t.by_name("prefix_insert", "request")
+        for e in chunks + pins + t.by_name("prefix_insert", "request"):
+            assert e[2] == "X" and _inside(e, step_spans)
+    else:
+        assert not chunks and not pins
+    h.release()
+
+
+def test_disabled_plane_reads_no_clock_on_the_paged_path(monkeypatch):
+    """With tracing off the new sites read no clock: the runner and the
+    app handle read none, and the engine's step reads only the clock it
+    always read (the first-token stamp, and the decode step's ends)."""
+    h = _paged_app(True)
+    _serve_paged(h, n=2)                   # compile every shape first
+    reads = {}
+    clock = time.perf_counter
+
+    def counted():
+        f = sys._getframe(1)
+        key = (f.f_globals.get("__name__"), f.f_code.co_name)
+        reads[key] = reads.get(key, 0) + 1
+        return clock()
+
+    engine_steps = []
+    step = ServingEngine.step
+
+    def counted_step(self):
+        engine_steps.append(1)
+        return step(self)
+
+    monkeypatch.setattr(time, "perf_counter", counted)
+    monkeypatch.setattr(ServingEngine, "step", counted_step)
+    d0 = h.engine.stats.decode_steps
+    _serve_paged(h, n=2)
+    monkeypatch.undo()
+    assert obs.current() is None
+    decodes = h.engine.stats.decode_steps - d0
+    assert decodes > 0
+    mine = {k: v for k, v in reads.items()
+            if k[0] in ("repro.serving.model_runner", "repro.runtime.cluster",
+                        "repro.serving.engine")}
+    assert mine == {("repro.serving.engine", "step"):
+                    len(engine_steps) + 2 * decodes}
+    h.release()
+
+
+def test_anchor_marks_both_clocks_without_a_profiler():
+    t = obs.enable()
+    before = time.perf_counter()
+    t.anchor()
+    t.anchor()
+    (a, b) = t.by_name("clock", "obs")
+    for e in (a, b):
+        assert e[2] == "i" and e[1] == 0.0 and e[5] is None
+        assert before <= e[6]["t_in"] == e[0] <= e[6]["t_out"]
+    assert a[6]["t_out"] <= b[6]["t_in"]
+
+
+def test_gc_spans_while_enabled_only():
+    obs.enable()
+    t = obs.enable()                   # a second enable keeps one hook
+
+    def hooks():
+        return [cb for cb in gc.callbacks
+                if isinstance(getattr(cb, "__self__", None), obs.Tracer)]
+
+    assert hooks() == [t._on_gc]
+    gc.collect()
+    spans = t.by_name("gc", "host")
+    assert spans and all(e[2] == "X" and e[1] >= 0.0 and e[6]["collected"]
+                         >= 0 for e in spans)
+    assert spans[-1][6]["generation"] == 2
+    assert obs.disable() is t and hooks() == []
+    gc.collect()
+    assert t.by_name("gc", "host") == spans
 
 
 # ---------------------------------------------------------------------------
