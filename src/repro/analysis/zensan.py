@@ -533,6 +533,20 @@ class Sanitizer:
                                f"local page {p} held by "
                                f"{sp.owner.get(p)!r}")
 
+    def decode_writes(self, writes) -> None:
+        """No two real decode lanes write into one block of a page:
+        ``kv_write`` rewrites each lane's whole block of rows, so of two
+        lanes sharing one only the last write survives.  ``writes`` is
+        (request id, page group, physical page, block) per real lane."""
+        self.events += 1
+        seen: Dict[Tuple, str] = {}
+        for req_id, group, page, block in writes:
+            other = seen.setdefault((group, page, block), req_id)
+            if other != req_id:
+                self._viol("write-collision",
+                           f"decode lanes {other!r} and {req_id!r} both "
+                           f"write block {block} of {group} page {page}")
+
     # -- dense backend slot table --------------------------------------------
     def dense_state(self, runner, running) -> None:
         """DenseRunner bookkeeping: every running request has a slot and

@@ -72,3 +72,19 @@ def paged_attention(q, k_pages, v_pages, page_table, valid_len, *,
                                       interpret=False)
     return _paged.paged_attention_ref(q, k_pages, v_pages, page_table,
                                       valid_len, window=window, ring=ring)
+
+
+def kv_write(k_pages, v_pages, k_new, v_new, phys, off):
+    if use_compiled_kernels():
+        return _paged.kv_write(k_pages, v_pages, k_new, v_new, phys, off,
+                               interpret=False)
+    return _paged.kv_write_ref(k_pages, v_pages, k_new, v_new, phys, off)
+
+
+# the paged pool's layout: whole-page reads and writes and the token <->
+# page reshapes, plain XLA on every backend (the pool layout lives in
+# paged_attention.py with the kernels that read and write it)
+get_pages = _paged.get_pages
+set_pages = _paged.set_pages
+tokens_to_pages = _paged.tokens_to_pages
+pages_to_tokens = _paged.pages_to_tokens

@@ -10,6 +10,12 @@ sequential; online-softmax state for the grouped queries lives in VMEM
 scratch.  Out-of-range pages (table entry < 0) are skipped via pl.when --
 requests shorter than max_pages cost only their own pages' DMAs.
 
+The pool is STORED in the layout the kernel reads, ``(KV, P, page, D)``,
+so no call relayouts it.  Decode writes its one new token per lane
+through :func:`kv_write`, a Pallas call aliasing the pool in and out:
+an XLA scatter of single rows into this layout makes the compiler move
+the whole pool into another layout around the scatter and back.
+
 Sliding-window (ATTN_LOCAL) layers run the same kernel with
 ``window > 0``: only keys at positions ``(pos - window, pos]`` score.
 With ``ring=True`` the page table is a fixed *ring* of
@@ -33,6 +39,7 @@ exists because the table is no longer uniformly view-local.
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -112,8 +119,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     page_table: jax.Array, valid_len: jax.Array, *,
                     window: int = 0, ring: bool = False,
                     interpret: bool) -> jax.Array:
-    """q: (B, H, D); k/v_pages: (P, page, KV, D) pool; page_table:
-    (B, max_pages) int32 (-1 padded); valid_len: (B,) total tokens.
+    """q: (B, H, D); k/v_pages: (KV, P, page, D) pool, stored head-major
+    so a block is one head's one page; page_table: (B, max_pages) int32
+    (-1 padded); valid_len: (B,) total tokens.
 
     Table entries are PHYSICAL page ids: the pool arrays may be a
     pod-shared :class:`~repro.serving.model_runner.KVArrayStore` aliased
@@ -127,13 +135,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     Returns (B, H, D)."""
     b, h, d = q.shape
-    pool, page, kvh, _ = k_pages.shape
+    kvh, _, page, _ = k_pages.shape
     g = h // kvh
     max_pages = page_table.shape[1]
     qg = q.reshape(b, kvh, g, d)
-    # pool laid out (KV, P, page, d) so a block is one head's one page
-    kp = k_pages.transpose(2, 0, 1, 3)
-    vp = v_pages.transpose(2, 0, 1, 3)
     vlen = jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (b,))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -163,7 +168,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), qg, kp, vp, vlen)
+    )(page_table.astype(jnp.int32), qg, k_pages, v_pages, vlen)
     return out.reshape(b, h, d)
 
 
@@ -172,11 +177,11 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, valid_len, *,
     """Gather-based jnp oracle (same window/ring semantics as the
     kernel)."""
     b, h, d = q.shape
-    pool, page, kvh, _ = k_pages.shape
+    kvh, _, page, _ = k_pages.shape
     max_pages = page_table.shape[1]
     safe = jnp.maximum(page_table, 0)                        # (B, MP)
-    k = k_pages[safe]                                        # (B, MP, page, KV, d)
-    v = v_pages[safe]
+    k = jnp.moveaxis(k_pages[:, safe], 0, 3)     # (B, MP, page, KV, d)
+    v = jnp.moveaxis(v_pages[:, safe], 0, 3)
     k = k.reshape(b, max_pages * page, kvh, d)
     v = v.reshape(b, max_pages * page, kvh, d)
     k = jnp.repeat(k, h // kvh, axis=2)
@@ -194,3 +199,101 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, valid_len, *,
     probs = jnp.where(mask, probs, 0.0)   # fully-masked rows stay finite
     return jnp.einsum("bhs,bshd->bhd", probs,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+#: rows of a page :func:`kv_write` rewrites per lane: bf16 packs two rows
+#: per sublane, and Mosaic slices it in (16, 128) tiles, never one row
+WRITE_ROWS = 16
+
+
+def _kv_write_kernel(phys_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
+                     ko_ref, vo_ref):
+    del phys_ref
+    row = off_ref[pl.program_id(0)] % WRITE_ROWS
+    hit = jax.lax.broadcasted_iota(jnp.int32, kp_ref.shape, 2) == row
+    for new, old, out in ((kn_ref, kp_ref, ko_ref), (vn_ref, vp_ref, vo_ref)):
+        out[...] = jnp.where(hit, new[...].astype(jnp.float32),
+                             old[...].astype(jnp.float32)).astype(out.dtype)
+
+
+def kv_write(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
+             v_new: jax.Array, phys: jax.Array, off: jax.Array, *,
+             interpret: bool) -> Tuple[jax.Array, jax.Array]:
+    """Write lane ``b``'s new token ``k_new[b]``/``v_new[b]`` (B, KV, D)
+    at row ``off[b]`` of physical page ``phys[b]`` of the (KV, P, page, D)
+    pools, in place: the pools are aliased in and out, and each grid step
+    rewrites only its lane's ``(KV, 1, WRITE_ROWS, D)`` block.
+
+    Lanes must not share a block, or one lane's write is lost: decode
+    writes only private pages, and the padded lanes that do share one
+    all write the trash page, which is never read."""
+    b, kvh, d = k_new.shape
+    # (KV, B, 1, D): a lane's rows are one block, broadcast over the rows
+    kn = k_new.transpose(1, 0, 2)[:, :, None, :]
+    vn = v_new.transpose(1, 0, 2)[:, :, None, :]
+    row_spec = pl.BlockSpec((kvh, 1, 1, d),
+                            lambda b_, ph, of: (0, b_, 0, 0))
+    page_spec = pl.BlockSpec((kvh, 1, WRITE_ROWS, d),
+                             lambda b_, ph, of: (0, ph[b_],
+                                                 of[b_] // WRITE_ROWS, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[row_spec, row_spec, page_spec, page_spec],
+        out_specs=[page_spec, page_spec],
+    )
+    return pl.pallas_call(
+        _kv_write_kernel,
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)),
+        # operands: phys, off, k rows, v rows, k pool, v pool
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+    )(phys.astype(jnp.int32), off.astype(jnp.int32), kn, vn, k_pages,
+      v_pages)
+
+
+def kv_write_ref(k_pages, v_pages, k_new, v_new, phys, off):
+    """Scatter oracle of :func:`kv_write`."""
+    return (k_pages.at[:, phys, off].set(
+                k_new.transpose(1, 0, 2).astype(k_pages.dtype)),
+            v_pages.at[:, phys, off].set(
+                v_new.transpose(1, 0, 2).astype(v_pages.dtype)))
+
+
+# Whole pages are read and written through the pool's (KV * P, page, D)
+# view, a bitcast: indexing its leading axis is the gather and scatter
+# XLA does in place, where indexing axis 1 of (KV, P, page, D) may make
+# the compiler move the whole pool into a page-major layout and back.
+
+def _page_rows(pool: jax.Array, ids) -> Tuple[jax.Array, jax.Array]:
+    """The pool's (KV * P, page, D) view and the rows of pages ``ids``
+    of every head in it, shaped (KV, *ids.shape)."""
+    kvh, p = pool.shape[:2]
+    ids = jnp.asarray(ids)
+    rows = jnp.arange(kvh).reshape((kvh,) + (1,) * ids.ndim) * p + ids
+    return pool.reshape(kvh * p, *pool.shape[2:]), rows
+
+
+def get_pages(pool: jax.Array, ids) -> jax.Array:
+    """``pool[:, ids]``: (KV, *ids.shape, page, D)."""
+    flat, rows = _page_rows(pool, ids)
+    return flat[rows]
+
+
+def set_pages(pool: jax.Array, ids, pages: jax.Array) -> jax.Array:
+    """``pool.at[:, ids].set(pages)``, cast to the pool's dtype."""
+    flat, rows = _page_rows(pool, ids)
+    return flat.at[rows].set(pages.astype(pool.dtype)).reshape(pool.shape)
+
+
+def tokens_to_pages(x: jax.Array, page: int) -> jax.Array:
+    """(n * page, KV, D) tokens -> (KV, n, page, D) pool pages."""
+    return x.reshape(-1, page, *x.shape[1:]).transpose(2, 0, 1, 3)
+
+
+def pages_to_tokens(pages: jax.Array) -> jax.Array:
+    """(KV, n, page, D) pool pages -> (n * page, KV, D) tokens."""
+    kvh, n, page, d = pages.shape
+    return pages.transpose(1, 2, 0, 3).reshape(n * page, kvh, d)

@@ -13,11 +13,12 @@ Two implementations:
   (the previous inline executor closure, extracted).  Decode attends over
   a contiguous cache via ``model.decode_step``; positions are shared
   across the batch (the historical approximation).
-* :class:`PagedRunner` -- KV lives in the ``(pool_pages, PAGE_SIZE, KV,
-  hd)`` layout granted page-by-page by the engine's pool; decode attends
+* :class:`PagedRunner` -- KV lives in the ``(KV, pool_pages, PAGE_SIZE,
+  hd)`` layout granted page-by-page by the engine's pool; decode writes
+  its token through :func:`repro.kernels.ops.kv_write` and attends
   through :func:`repro.kernels.ops.paged_attention` (the compiled Pallas
-  kernel on TPU, its jnp oracle elsewhere -- ``ops`` decides) driven by
-  each request's page table.
+  kernels on TPU, their jnp oracles elsewhere -- ``ops`` decides) driven
+  by each request's page table.
   Positions and valid lengths are exact per request, so co-batched
   requests of different lengths decode correctly -- and the KV footprint
   is the pages the sizing policy granted, not ``max_batch * cache_len``.
@@ -60,6 +61,7 @@ from repro.checkpoint.checkpointer import _from_saved, _to_savable
 from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
 from repro.obs import trace as obs_trace
 from repro.kernels import ops
+from repro.kernels.paged_attention import WRITE_ROWS
 from repro.models import ImplConfig, build_model
 from repro.models import attention as attn
 from repro.models import layers as L
@@ -91,8 +93,9 @@ class KVArrayStore:
     Registered on the pod's :class:`~repro.serving.tenancy.SharedPagePool`
     keyed by :func:`kv_shape_key`; every same-shape paged tenant's
     :class:`PagedRunner` reads and writes THESE arrays (per-layer
-    ``(pool_pages + 1, PAGE_SIZE, KV, hd)``, last slot = shared trash
-    page), indexed by pod-unique physical page ids.  N same-model
+    ``(KV, pool_pages + 1, PAGE_SIZE, hd)``, the layout the paged kernel
+    reads; last page = shared trash page), indexed on axis 1 by
+    pod-unique physical page ids.  N same-model
     tenants therefore cost ONE pool of device HBM instead of N -- the
     pool's accounted footprint and the live footprint finally coincide.
 
@@ -109,7 +112,7 @@ class KVArrayStore:
         self.key = key
         self.num_layers = num_layers
         self.dtype = dtype
-        self.page_shape = (pool_pages + 1, page, kvh, hd)
+        self.page_shape = (kvh, pool_pages + 1, page, hd)
         self.k_pages: Optional[List[jax.Array]] = None
         self.v_pages: Optional[List[jax.Array]] = None
         self.free_local: Optional[List[int]] = (
@@ -488,13 +491,13 @@ class PagedRunner(ModelRunner):
 
     @staticmethod
     def _scatter_fn(kp, vp, pages, k, v):
-        return (kp.at[pages].set(k.astype(KV_DTYPE)),
-                vp.at[pages].set(v.astype(KV_DTYPE)))
+        return ops.set_pages(kp, pages, k), ops.set_pages(vp, pages, v)
 
     @staticmethod
     def _copy_fn(kp, vp, src, dst):
         """Copy-on-write page duplication (one layer's arrays, donated)."""
-        return kp.at[dst].set(kp[src]), vp.at[dst].set(vp[src])
+        return (ops.set_pages(kp, dst, ops.get_pages(kp, src)),
+                ops.set_pages(vp, dst, ops.get_pages(vp, src)))
 
     def _cow_copy(self, src_phys: int, dst_phys: int) -> None:
         """Duplicate one physical page's KV across every layer (the
@@ -520,10 +523,60 @@ class PagedRunner(ModelRunner):
         h = T.apply_norm(cfg, bp["ln2"], x)
         return x + L.gated_mlp(bp["mlp"], h)
 
+    def _scan_stack(self, params, x, positions, mix, read=None,
+                    span: int = 0):
+        """The prompt programs' layer stack as ``lax.scan`` over pattern
+        blocks, so a program holds one block's code however deep the
+        stack: unrolled, each prompt shape's program held every layer's
+        code (about 100 MB at 32 layers and 512 tokens, resident in HBM
+        beside the pool once it has run).
+
+        ``mix(q, k, v, kind, extra) -> (out, saved)`` attends for one
+        layer, given its slice ``extra`` of ``read(l0, l1)``: layers
+        [l0, l1)'s inputs from the pool, stacked on a leading layer axis
+        (a scan cannot pick a layer's pool array, only slice a stack).
+        With ``span`` the stack runs as several scans of at most ``span``
+        layers, so that no more is read ahead at once.  Returns the
+        stack's output and each layer's ``saved``, stacked on a leading
+        layer axis: the pool is written after the stack, one scatter per
+        layer."""
+        cfg = self.cfg
+        nb, pat = cfg.num_blocks, len(cfg.pattern)
+        blocks = [params["blocks"][f"p{i}_{kind}"]
+                  for i, kind in enumerate(cfg.pattern)]
+        span = max(1, min(nb, span // pat)) if span else nb
+
+        def body(x, inp):
+            j, extra = inp
+            saved = []
+            for i, kind in enumerate(cfg.pattern):
+                bp = jax.tree.map(lambda a: a[j], blocks[i])
+
+                def block_mix(q, k, v, i=i, kind=kind):
+                    out, keep = mix(q, k, v, kind,
+                                    jax.tree.map(lambda a: a[i], extra))
+                    saved.append(keep)
+                    return out
+
+                x = self._block_forward(bp, x, positions, block_mix)
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *saved)
+
+        ys = []
+        for b0 in range(0, nb, span):
+            b1 = min(b0 + span, nb)
+            extra = () if read is None else jax.tree.map(
+                lambda a: a.reshape(b1 - b0, pat, *a.shape[1:]),
+                read(b0 * pat, b1 * pat))
+            x, y = jax.lax.scan(body, x, (jnp.arange(b0, b1), extra))
+            ys.append(y)
+        return x, jax.tree.map(
+            lambda *a: jnp.concatenate(a).reshape(nb * pat,
+                                                  *a[0].shape[2:]), *ys)
+
     # -- prefill -------------------------------------------------------------
     def _prefill_fn(self, params, toks, last, g_ids, l_ids, l_src,
                     k_pages, v_pages):
-        """Forward over the (page-padded) prompt, scattering each layer's
+        """Forward over the (page-padded) prompt, then scatter each layer's
         KV page-by-page into the granted ids: no dense ``cache_len``
         cache, no per-grant-size recompile (the compile key is the padded
         prompt page count only).  ``last`` is the index of the final real
@@ -535,34 +588,27 @@ class PagedRunner(ModelRunner):
             t.instant("compile", "prefill_trace", None,
                       {"backend": "paged", "tokens": toks.shape[1]})
         cfg = self.cfg
-        s = toks.shape[1]
-        n_pg = s // PAGE_SIZE
-        positions = jnp.arange(s)
-        x = self.model._embed(params, toks)
+        positions = jnp.arange(toks.shape[1])
+
+        def mix(q, k, v, kind, _):
+            window = cfg.sliding_window if kind == ATTN_LOCAL else 0
+            out = attn.sdpa(q, k, v, causal=True, window=window,
+                            q_positions=positions, k_positions=positions)
+            return out, tuple(ops.tokens_to_pages(a[0], PAGE_SIZE)
+                              .astype(KV_DTYPE) for a in (k, v))
+
+        x, (kpg, vpg) = self._scan_stack(
+            params, self.model._embed(params, toks), positions, mix)
         new_k, new_v = list(k_pages), list(v_pages)
         for layer in range(len(new_k)):
-            j, i = divmod(layer, len(cfg.pattern))
-            kind = cfg.pattern[i]
-            bp = jax.tree.map(lambda a: a[j],
-                              params["blocks"][f"p{i}_{kind}"])
-
-            def mix(q, k, v, layer=layer, kind=kind):
-                kpg = k[0].reshape(n_pg, PAGE_SIZE, cfg.num_kv_heads,
-                                   cfg.head_dim).astype(KV_DTYPE)
-                vpg = v[0].reshape(n_pg, PAGE_SIZE, cfg.num_kv_heads,
-                                   cfg.head_dim).astype(KV_DTYPE)
-                if self._layer_ring(layer):
-                    new_k[layer] = new_k[layer].at[l_ids].set(kpg[l_src])
-                    new_v[layer] = new_v[layer].at[l_ids].set(vpg[l_src])
-                else:
-                    new_k[layer] = new_k[layer].at[g_ids].set(kpg)
-                    new_v[layer] = new_v[layer].at[g_ids].set(vpg)
-                window = cfg.sliding_window if kind == ATTN_LOCAL else 0
-                return attn.sdpa(q, k, v, causal=True, window=window,
-                                 q_positions=positions,
-                                 k_positions=positions)
-
-            x = self._block_forward(bp, x, positions, mix)
+            if self._layer_ring(layer):
+                ids, src = l_ids, l_src
+            else:
+                ids, src = g_ids, slice(None)
+            new_k[layer] = ops.set_pages(new_k[layer], ids,
+                                         kpg[layer][:, src])
+            new_v[layer] = ops.set_pages(new_v[layer], ids,
+                                         vpg[layer][:, src])
         x = T.apply_norm(cfg, params["ln_f"], x)
         xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
         logits = L.unembed(params["embed"], xl, cfg.logit_softcap)
@@ -658,57 +704,48 @@ class PagedRunner(ModelRunner):
                        "ctx_w": ctx_table.shape[0]})
         cfg = self.cfg
         s = toks.shape[1]
-        n_pg = s // PAGE_SIZE
         w = ctx_table.shape[0]
         positions = base + jnp.arange(s)
         k_pos = jnp.concatenate([jnp.arange(w * PAGE_SIZE), positions])
         k_valid = jnp.concatenate(
             [jnp.repeat(ctx_table >= 0, PAGE_SIZE),
              jnp.ones(s, bool)])
-        lead_mask = (jnp.arange(PAGE_SIZE) < lead)[:, None, None]
-        x = self.model._embed(params, toks)
-        new_k, new_v = list(k_pages), list(v_pages)
-        for layer in range(len(new_k)):
-            j, i = divmod(layer, len(cfg.pattern))
-            kind = cfg.pattern[i]
-            bp = jax.tree.map(lambda a: a[j],
-                              params["blocks"][f"p{i}_{kind}"])
+        lead_mask = (jnp.arange(PAGE_SIZE) < lead)[:, None]
+        # each layer's COW lead page and context pages, read before the
+        # chunk's pages are written (after the stack): both are disjoint
+        # from g_ids (the lead is a cached page, the context strictly
+        # earlier pages), so they hold cached/earlier-chunk KV only
+        ctx = jnp.maximum(ctx_table, 0)
 
-            def mix(q, k, v, layer=layer):
-                kpg = k[0].reshape(n_pg, PAGE_SIZE, cfg.num_kv_heads,
-                                   cfg.head_dim)
-                vpg = v[0].reshape(n_pg, PAGE_SIZE, cfg.num_kv_heads,
-                                   cfg.head_dim)
-                kpg = kpg.at[0].set(jnp.where(
-                    lead_mask, new_k[layer][cow_src].astype(k.dtype),
-                    kpg[0]))
-                vpg = vpg.at[0].set(jnp.where(
-                    lead_mask, new_v[layer][cow_src].astype(v.dtype),
-                    vpg[0]))
-                new_k[layer] = new_k[layer].at[g_ids].set(
-                    kpg.astype(KV_DTYPE))
-                new_v[layer] = new_v[layer].at[g_ids].set(
-                    vpg.astype(KV_DTYPE))
-                # context pages are read back AFTER the scatter: they are
-                # disjoint from g_ids (strictly earlier absolute pages),
-                # so the gather sees cached/earlier-chunk KV only
-                ctx_k = new_k[layer][jnp.maximum(ctx_table, 0)].reshape(
-                    1, w * PAGE_SIZE, cfg.num_kv_heads,
-                    cfg.head_dim).astype(k.dtype)
-                ctx_v = new_v[layer][jnp.maximum(ctx_table, 0)].reshape(
-                    1, w * PAGE_SIZE, cfg.num_kv_heads,
-                    cfg.head_dim).astype(v.dtype)
-                k_cat = jnp.concatenate(
-                    [ctx_k, kpg.reshape(1, s, cfg.num_kv_heads,
-                                        cfg.head_dim)], axis=1)
-                v_cat = jnp.concatenate(
-                    [ctx_v, vpg.reshape(1, s, cfg.num_kv_heads,
-                                        cfg.head_dim)], axis=1)
-                return attn.sdpa(q, k_cat, v_cat, causal=True,
-                                 q_positions=positions, k_positions=k_pos,
-                                 k_valid=k_valid)
+        def read(l0, l1):
+            return tuple(jnp.stack([ops.get_pages(pool, ids)
+                                    for pool in pools[l0:l1]])
+                         for pools in (k_pages, v_pages)
+                         for ids in (cow_src, ctx))
 
-            x = self._block_forward(bp, x, positions, mix)
+        def mix(q, k, v, kind, extra):
+            k_lead, k_ctx, v_lead, v_ctx = extra
+            kv_pages, kv_cat = [], []
+            for a, a_lead, a_ctx in ((k, k_lead, k_ctx), (v, v_lead, v_ctx)):
+                pg = ops.tokens_to_pages(a[0], PAGE_SIZE)
+                pg = pg.at[:, 0].set(jnp.where(
+                    lead_mask, a_lead.astype(a.dtype), pg[:, 0]))
+                kv_pages.append(pg.astype(KV_DTYPE))
+                kv_cat.append(ops.pages_to_tokens(jnp.concatenate(
+                    [a_ctx.astype(a.dtype), pg], axis=1))[None])
+            out = attn.sdpa(q, kv_cat[0], kv_cat[1], causal=True,
+                            q_positions=positions, k_positions=k_pos,
+                            k_valid=k_valid)
+            return out, tuple(kv_pages)
+
+        x, (kpg, vpg) = self._scan_stack(
+            params, self.model._embed(params, toks), positions, mix, read,
+            # read ahead no more pages than one layer's pool array holds
+            span=k_pages[0].shape[1] // (w + 1))
+        new_k = [ops.set_pages(p, g_ids, kpg[l])
+                 for l, p in enumerate(k_pages)]
+        new_v = [ops.set_pages(p, g_ids, vpg[l])
+                 for l, p in enumerate(v_pages)]
         x = T.apply_norm(cfg, params["ln_f"], x)
         xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
         logits = L.unembed(params["embed"], xl, cfg.logit_softcap)
@@ -892,11 +929,10 @@ class PagedRunner(ModelRunner):
 
             def mix(q, k, v, layer=layer, kind=kind):
                 ring = self._layer_ring(layer)
-                phys = phys_l if ring else phys_g
-                kp = new_k[layer].at[phys, off].set(
-                    k[:, 0].astype(KV_DTYPE))
-                vp = new_v[layer].at[phys, off].set(
-                    v[:, 0].astype(KV_DTYPE))
+                kp, vp = ops.kv_write(
+                    new_k[layer], new_v[layer], k[:, 0].astype(KV_DTYPE),
+                    v[:, 0].astype(KV_DTYPE), phys_l if ring else phys_g,
+                    off)
                 new_k[layer], new_v[layer] = kp, vp
                 o = ops.paged_attention(
                     q[:, 0], kp, vp, table_l if ring else table_g, vlen,
@@ -952,6 +988,14 @@ class PagedRunner(ModelRunner):
             # table must be this view's grant or a cache page
             s.table(self.engine.pool if self.engine is not None else None,
                     g_phys, l_phys)
+            # kv_write rewrites a block of rows per lane: real lanes must
+            # write distinct blocks (decode writes only private pages)
+            blk = [p % PAGE_SIZE // WRITE_ROWS for p in pos]
+            s.decode_writes(
+                [(r.req_id, "global", g[p // PAGE_SIZE], bk)
+                 for r, g, p, bk in zip(running, g_phys, pos, blk) if g]
+                + [(r.req_id, "ring", l[(p // PAGE_SIZE) % ring], bk)
+                   for r, l, p, bk in zip(running, l_phys, pos, blk) if l])
         maxp_b = _next_pow2(max(max(len(p) for p in g_phys), 1))
         toks = np.zeros((b, 1), np.int32)
         positions = np.zeros((b, 1), np.int32)
@@ -1005,7 +1049,7 @@ class PagedRunner(ModelRunner):
     # -- parking -------------------------------------------------------------
     def park(self, drained):
         """Snapshot ONLY the view's pages: gather each drained request's
-        KV to host (per layer group: one (layers, n_pages, PAGE, KV, hd)
+        KV to host (per layer group: one (layers, KV, n_pages, PAGE, hd)
         array for the growing tables and one for the rings -- ``drained``
         carries the *physical* ids ``reclaim`` translated before
         freeing).  The pool-sized device arrays are dropped only when no
@@ -1050,7 +1094,7 @@ class PagedRunner(ModelRunner):
 
     def _gather_drained(self, drained):
         """Host snapshot of each drained request's KV, keyed by request:
-        per layer group one (layers, n_pages, PAGE, KV, hd) array for the
+        per layer group one (layers, KV, n_pages, PAGE, hd) array for the
         growing tables and one for the rings.  ``drained`` carries the
         *physical* ids ``reclaim`` translated before freeing."""
         table_layers, ring_layers = self._layer_split()
@@ -1059,8 +1103,10 @@ class PagedRunner(ModelRunner):
             if not layers or not ids:
                 return None
             idx = jnp.asarray(ids, jnp.int32)
-            k = np.stack([np.asarray(self.k_pages[l][idx]) for l in layers])
-            v = np.stack([np.asarray(self.v_pages[l][idx]) for l in layers])
+            k = np.stack([np.asarray(ops.get_pages(self.k_pages[l], idx))
+                          for l in layers])
+            v = np.stack([np.asarray(ops.get_pages(self.v_pages[l], idx))
+                          for l in layers])
             return (_to_savable(k), _to_savable(v))
 
         kv = {}
@@ -1085,7 +1131,7 @@ class PagedRunner(ModelRunner):
                 if packed is None:
                     continue
                 (ka, kd), (va, vd) = packed
-                k = jnp.asarray(_from_saved(ka, kd))   # (L, n, PAGE, KV, hd)
+                k = jnp.asarray(_from_saved(ka, kd))   # (L, KV, n, PAGE, hd)
                 v = jnp.asarray(_from_saved(va, vd))
                 pages = jnp.asarray(ids, jnp.int32)
                 for li, layer in enumerate(layers):

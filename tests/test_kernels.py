@@ -184,7 +184,8 @@ def test_rmsnorm(rows, d, dtype):
 
 
 # ---------------------------------------------------------------------------
-# paged attention (scalar-prefetch page tables)
+# paged attention (scalar-prefetch page tables); pools are head-major
+# (KV, P, page, d), as the serving runner stores them
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("b,h,kvh,d,pool,page,maxp", [
@@ -193,7 +194,7 @@ def test_paged_attention(b, h, kvh, d, pool, page, maxp):
     from repro.kernels.paged_attention import (paged_attention,
                                                paged_attention_ref)
     q = ra(b, h, d)
-    kp, vp = ra(pool, page, kvh, d), ra(pool, page, kvh, d)
+    kp, vp = ra(kvh, pool, page, d), ra(kvh, pool, page, d)
     tables = []
     for i in range(b):
         n = int(RNG.integers(1, maxp + 1))
@@ -219,7 +220,7 @@ def test_paged_ref_matches_dense_oracle(b, h, kvh, d, pool, page, maxp):
     from repro.models.attention import gqa_decode_sdpa
 
     q = ra(b, h, d)
-    kp, vp = ra(pool, page, kvh, d), ra(pool, page, kvh, d)
+    kp, vp = ra(kvh, pool, page, d), ra(kvh, pool, page, d)
     tables, vlens = [], []
     for _ in range(b):
         n = int(RNG.integers(1, maxp + 1))
@@ -233,10 +234,8 @@ def test_paged_ref_matches_dense_oracle(b, h, kvh, d, pool, page, maxp):
     for i in range(b):
         own = [p for p in tables[i] if p >= 0]
         # gather this request's pages contiguously: (1, KV, S, d)
-        k = kp[jnp.asarray(own)].reshape(len(own) * page, kvh, d)
-        v = vp[jnp.asarray(own)].reshape(len(own) * page, kvh, d)
-        k = k.transpose(1, 0, 2)[None]
-        v = v.transpose(1, 0, 2)[None]
+        k = kp[:, jnp.asarray(own)].reshape(1, kvh, len(own) * page, d)
+        v = vp[:, jnp.asarray(own)].reshape(1, kvh, len(own) * page, d)
         k_valid = jnp.arange(len(own) * page) < vlens[i]
         o_dense = gqa_decode_sdpa(q[i:i + 1, None], k, v, k_valid)
         np.testing.assert_allclose(np.asarray(o[i]),
@@ -253,8 +252,10 @@ def test_paged_matches_contiguous_decode():
     vlen = jnp.asarray([s - 7, s // 2], jnp.int32)
     dense = _dec.decode_attention(q, k, v, vlen, block_s=page, interpret=True)
     # build a per-request page pool from the contiguous cache
-    kp = k.transpose(0, 2, 1, 3).reshape(b * npg, page, kvh, d)
-    vp = v.transpose(0, 2, 1, 3).reshape(b * npg, page, kvh, d)
+    kp = k.reshape(b, kvh, npg, page, d).transpose(1, 0, 2, 3, 4).reshape(
+        kvh, b * npg, page, d)
+    vp = v.reshape(b, kvh, npg, page, d).transpose(1, 0, 2, 3, 4).reshape(
+        kvh, b * npg, page, d)
     table = jnp.arange(b * npg, dtype=jnp.int32).reshape(b, npg)
     paged = _paged.paged_attention(q, kp, vp, table, vlen, interpret=True)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
@@ -274,16 +275,16 @@ def test_paged_attention_ring_window(pos_last):
     vlen = pos_last + 1
     keys = np.asarray(ra(vlen, kvh, d), np.float32)
     vals = np.asarray(ra(vlen, kvh, d), np.float32)
-    kp = np.zeros((pool, page, kvh, d), np.float32)
-    vp = np.zeros((pool, page, kvh, d), np.float32)
+    kp = np.zeros((kvh, pool, page, d), np.float32)
+    vp = np.zeros((kvh, pool, page, d), np.float32)
     ring_ids = [7, 2, 5][:min(ring_pages, -(-vlen // page))]
     table = np.full((1, ring_pages), -1, np.int32)
     table[0, :len(ring_ids)] = ring_ids
     for p in range(vlen):          # write each token at its ring slot
         pg, off = divmod(p % ring_tokens, page)
         if pg < len(ring_ids):
-            kp[ring_ids[pg], off] = keys[p]
-            vp[ring_ids[pg], off] = vals[p]
+            kp[:, ring_ids[pg], off] = keys[p]
+            vp[:, ring_ids[pg], off] = vals[p]
     q = np.asarray(ra(1, h, d), np.float32)
     args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(table), jnp.asarray([vlen]))
@@ -303,6 +304,52 @@ def test_paged_attention_ring_window(pos_last):
                                rtol=2e-5, atol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# kv_write: decode's one-token write into the head-major pool
+# ---------------------------------------------------------------------------
+
+_KVW_PAGE, _KVW_POOL = 128, 10         # page 9 is the trash page
+
+
+def _ring_lanes():
+    """Write slots of three requests decoding through 3-page rings of
+    32-token pages: token ``p`` goes to ring page ``(p // 32) % 3`` at
+    row ``p % 32``."""
+    rings, pos, page = [[5, 0, 3], [1, 4, 2], [6, 8, 7]], [95, 130, 47], 32
+    return ([r[(p // page) % 3] for r, p in zip(rings, pos)],
+            [p % page for p in pos], page)
+
+
+@pytest.mark.parametrize("case", ["offsets", "ring", "padded"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kv_write(case, dtype):
+    """Each lane's row lands at ``[:, phys, off]``; the other rows of its
+    16-row block and every other page are unchanged (padded lanes all
+    write the trash page, whose content is never read)."""
+    page, trash = _KVW_PAGE, _KVW_POOL - 1
+    if case == "offsets":                 # block edges and the last row
+        phys, off = [2, 0, 4, 1], [0, 15, 16, 127]
+    elif case == "ring":
+        phys, off, page = _ring_lanes()
+    else:                                 # 2 real lanes, 3 padded
+        phys, off = [3, 1, trash, trash, trash], [40, 9, 0, 0, 0]
+    kvh, d, b = 2, 128, len(phys)
+    kp, vp = (ra(kvh, _KVW_POOL, page, d, dtype=dtype) for _ in range(2))
+    kn, vn = ra(b, kvh, d, dtype=dtype), ra(b, kvh, d, dtype=dtype)
+    args = (kn, vn, jnp.asarray(phys, jnp.int32),
+            jnp.asarray(off, jnp.int32))
+    got = _paged.kv_write(kp, vp, *args, interpret=True)
+    want = _paged.kv_write_ref(kp, vp, *args)
+    for pool, new, g_, w_ in zip((kp, vp), (kn, vn), got, want):
+        g_, w_ = np.asarray(g_, np.float32), np.asarray(w_, np.float32)
+        expect = np.asarray(pool, np.float32).copy()
+        for i, (p, o) in enumerate(zip(phys, off)):
+            if p != trash:
+                expect[:, p, o] = np.asarray(new[i], np.float32)
+        np.testing.assert_array_equal(g_[:, :trash], expect[:, :trash])
+        np.testing.assert_array_equal(w_[:, :trash], expect[:, :trash])
+
+
 def test_ops_runs_the_oracles_off_tpu():
     """Off TPU every ``ops`` wrapper is its jnp oracle (exactly), so no
     serving path reaches the interpreter; on TPU ``use_compiled_kernels``
@@ -310,7 +357,7 @@ def test_ops_runs_the_oracles_off_tpu():
     assert not ops.use_compiled_kernels()
     b, h, kvh, d, pool, page = 2, 4, 2, 32, 6, 16
     q = ra(b, h, d)
-    kp, vp = ra(pool, page, kvh, d), ra(pool, page, kvh, d)
+    kp, vp = ra(kvh, pool, page, d), ra(kvh, pool, page, d)
     table = jnp.asarray([[3, 1, -1], [0, 5, 2]], jnp.int32)
     vlen = jnp.asarray([20, 40], jnp.int32)
     got = ops.paged_attention(q, kp, vp, table, vlen)
@@ -318,6 +365,15 @@ def test_ops_runs_the_oracles_off_tpu():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     text = jax.jit(lambda *a: ops.paged_attention(*a)).lower(
         q, kp, vp, table, vlen).as_text()
+    assert "pallas" not in text and "tpu_custom_call" not in text
+    kn, vn = ra(b, kvh, d), ra(b, kvh, d)
+    phys, off = jnp.asarray([4, 1]), jnp.asarray([3, 15])
+    got = ops.kv_write(kp, vp, kn, vn, phys, off)
+    want = _paged.kv_write_ref(kp, vp, kn, vn, phys, off)
+    for g_, w_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_))
+    text = jax.jit(lambda *a: ops.kv_write(*a)).lower(
+        kp, vp, kn, vn, phys, off).as_text()
     assert "pallas" not in text and "tpu_custom_call" not in text
     x, g = ra(8, 64), ra(64, scale=0.1)
     np.testing.assert_array_equal(np.asarray(ops.rmsnorm(x, g)),

@@ -545,3 +545,37 @@ def test_engine_with_real_model(rng):
         eng.submit(Request(f"r{i}", prompt_len=4, max_new_tokens=5))
     stats = eng.run_to_completion(max_steps=200)
     assert stats.completed == 3
+
+
+def test_chunk_stack_split_into_scans_agrees(monkeypatch):
+    """A prefill chunk gives the same token and pool pages whether its
+    layer stack runs as one scan or as one scan per block (the split
+    that bounds how much context each scan reads ahead)."""
+    from repro.serving.model_runner import PagedRunner
+    cfg = reduced_config(get_config("tinyllama-1.1b")).scaled(num_layers=3)
+    runner = PagedRunner(cfg, pool_pages=8, max_batch=1)
+    assert cfg.num_blocks == 3
+    g = np.random.default_rng(0)
+    shape = runner.page_shape
+    kv = [[jnp.asarray(g.normal(size=shape), jnp.bfloat16)
+           for _ in range(runner.num_layers)] for _ in range(2)]
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    args = (runner.params, i32(g.integers(0, cfg.vocab_size,
+                                          (1, 2 * PAGE_SIZE))),
+            i32(5), i32(3 * PAGE_SIZE), i32(2 * PAGE_SIZE - 1),
+            i32([4, 5]), i32(3), i32([0, 1, 2, -1]))
+    whole = runner._chunk_fn(*args, *kv)
+    scan = runner._scan_stack
+    calls = []
+
+    def one_block(*a, span=0, **kw):
+        calls.append(span)
+        return scan(*a, span=len(cfg.pattern), **kw)
+
+    monkeypatch.setattr(runner, "_scan_stack", one_block)
+    split = runner._chunk_fn(*args, *kv)
+    assert calls
+    assert int(whole[0]) == int(split[0])
+    for a, b in zip(whole[1] + whole[2], split[1] + split[2]):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

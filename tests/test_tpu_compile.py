@@ -13,7 +13,9 @@ entry written for a described chip cannot be read back without one.
 """
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ import pytest
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.kernels.paged_attention import paged_attention
+from repro.models import ImplConfig, build_model
 from repro.serving.kv_cache import PAGE_SIZE, PageGroups
 from repro.serving.model_runner import PagedRunner
 
@@ -69,7 +72,7 @@ def _assert_fits(compiled):
 def test_paged_kernel_linear_table(one_chip, arch):
     cfg = get_config(arch)
     b, pool, width = 8, 257, 16
-    kv = _struct(one_chip, (pool, PAGE_SIZE, cfg.num_kv_heads, cfg.head_dim),
+    kv = _struct(one_chip, (cfg.num_kv_heads, pool, PAGE_SIZE, cfg.head_dim),
                  jnp.bfloat16)
     compiled = jax.jit(functools.partial(paged_attention,
                                          interpret=False)).lower(
@@ -83,7 +86,7 @@ def test_paged_kernel_ring_table(one_chip):
     cfg = get_config("gemma3-12b")
     ring = PageGroups.from_config(cfg).ring_pages
     b, pool = 8, 257
-    kv = _struct(one_chip, (pool, PAGE_SIZE, cfg.num_kv_heads, cfg.head_dim),
+    kv = _struct(one_chip, (cfg.num_kv_heads, pool, PAGE_SIZE, cfg.head_dim),
                  jnp.bfloat16)
     compiled = jax.jit(functools.partial(
         paged_attention, window=cfg.sliding_window, ring=True,
@@ -148,3 +151,133 @@ def test_paged_chunk_step(one_chip, runner, monkeypatch):
         params, i32(1, n * PAGE_SIZE), i32(), i32(), i32(), i32(n), i32(),
         i32(ctx), kp, vp).compile()
     _assert_fits(compiled)
+
+
+# -- the pool is never moved whole -----------------------------------------
+#
+# Serving widths and pool sizes of the two benchmark deployments, 2 layers.
+# The runner is built on a 1-page pool with weight shapes only; the
+# programs are compiled for the real pool, whose size they do not bake in.
+
+#: Phi-3-mini's published widths (its family here is the llama-style stack)
+PHI3_WIDTHS = dict(name="phi3-mini-widths", d_model=3072, num_heads=32,
+                   num_kv_heads=32, head_dim=96, d_ff=8192, vocab_size=32064)
+
+
+def _serving_runner(arch, batch, layers=2):
+    if arch == "phi3-mini":
+        cfg = get_config("tinyllama-1.1b").scaled(num_layers=layers,
+                                                  **PHI3_WIDTHS)
+    else:
+        cfg = get_config(arch).scaled(num_layers=layers)
+    model = build_model(cfg, ImplConfig(remat="none"))
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    return PagedRunner(cfg, pool_pages=1, max_batch=batch, params=params)
+
+
+def _pool_structs(runner, one_chip, pool_pages):
+    cfg = runner.cfg
+    shape = (cfg.num_kv_heads, pool_pages + 1, PAGE_SIZE, cfg.head_dim)
+    params = jax.tree.map(lambda x: _struct(one_chip, x.shape, x.dtype),
+                          runner.params)
+    kv = [_struct(one_chip, shape, jnp.bfloat16)
+          for _ in range(runner.num_layers)]
+    return params, kv, shape
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]")
+
+
+def _pool_moves(text, shape):
+    """Names of the copies and transposes whose output holds a whole
+    layer's pool, in any order of its dimensions."""
+    want = sorted(shape)
+    found = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if (m and re.match(r"(copy|transpose)", m.group(1))
+                and sorted(int(x) for x in m.group(2).split(",") if x)
+                == want):
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("arch,pool_pages,batch,width,per_array", [
+    ("mistral-nemo-12b", 1200, 32, 64, 0),
+    # hd 96: the compact default layout of the pool puts the page dimension
+    # minor, and each array is converted to row-major for the Pallas calls
+    # and back once a step; a third pass would show up here
+    ("phi3-mini", 144, 16, 16, 2),
+])
+def test_decode_step_pool_copies(one_chip, monkeypatch, arch, pool_pages,
+                                 batch, width, per_array):
+    """The decode step writes its tokens and attends over the pool where
+    it lies: no copy of the whole pool (beyond phi-3's layout
+    conversions), and no temporary the size of one."""
+    monkeypatch.setattr(ops, "use_compiled_kernels", lambda: True)
+    runner = _serving_runner(arch, batch)
+    params, kv, shape = _pool_structs(runner, one_chip, pool_pages)
+    b = batch
+    i32 = functools.partial(_i32, one_chip)
+    compiled = jax.jit(runner._decode_fn, donate_argnums=(9, 10)).lower(
+        params, i32(b, 1), i32(b, 1), i32(b), i32(b), i32(b), i32(b, width),
+        i32(b, 1), i32(b), kv, kv).compile()
+    moves = _pool_moves(compiled.as_text(), shape)
+    assert len(moves) <= per_array * 2 * runner.num_layers, moves
+    if per_array == 0:
+        pool_bytes = 2 * math.prod(shape)   # bf16
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < pool_bytes, f"{temp} bytes of temporaries"
+    _assert_fits(compiled)
+
+
+@pytest.mark.parametrize("arch,prog,n,ctx", [
+    ("mistral-nemo-12b", "chunk", 4, 16),
+    # one page over a one-page context: indexing the pool's page axis
+    # directly here had the compiler move the whole pool page-major
+    ("mistral-nemo-12b", "chunk", 1, 1),
+    ("phi3-mini", "prefill", 4, 0),
+])
+def test_prompt_programs_pool_copies(one_chip, monkeypatch, arch, prog, n,
+                                     ctx):
+    """Prompt programs scatter and gather whole pages in place: nemo's
+    chunks of ``n`` pages over ``ctx`` context pages, phi-3's 4-page
+    native prefill."""
+    monkeypatch.setattr(ops, "use_compiled_kernels", lambda: True)
+    pool_pages = 1200 if arch == "mistral-nemo-12b" else 144
+    runner = _serving_runner(arch, 1)
+    compiled, shape = _compile_prompt(one_chip, runner, prog, n, ctx,
+                                      pool_pages)
+    assert _pool_moves(compiled.as_text(), shape) == []
+    _assert_fits(compiled)
+
+
+def _compile_prompt(one_chip, runner, prog, n, ctx, pool_pages):
+    params, kv, shape = _pool_structs(runner, one_chip, pool_pages)
+    i32 = functools.partial(_i32, one_chip)
+    toks = i32(1, n * PAGE_SIZE)
+    if prog == "chunk":
+        lowered = jax.jit(runner._chunk_fn, donate_argnums=(8, 9)).lower(
+            params, toks, i32(), i32(), i32(), i32(n), i32(), i32(ctx),
+            kv, kv)
+    else:
+        lowered = jax.jit(runner._prefill_fn, donate_argnums=(6, 7)).lower(
+            params, toks, i32(), i32(n), i32(0), i32(0), kv, kv)
+    return lowered.compile(), shape
+
+
+@pytest.mark.parametrize("prog,n,ctx", [("prefill", 4, 0), ("chunk", 4, 16)])
+def test_prompt_program_code_does_not_grow_with_depth(one_chip, monkeypatch,
+                                                      prog, n, ctx):
+    """A prompt program holds one block's code however deep the stack:
+    each prompt shape's program stays resident in HBM once it has run,
+    and unrolled over phi-3's 32 layers a 4-page one held about 100 MB
+    of code."""
+    monkeypatch.setattr(ops, "use_compiled_kernels", lambda: True)
+    code = {}
+    for layers in (2, 16):
+        runner = _serving_runner("phi3-mini", 1, layers)
+        compiled, _ = _compile_prompt(one_chip, runner, prog, n, ctx, 144)
+        code[layers] = compiled.memory_analysis().generated_code_size_in_bytes
+    # unrolled, 16 layers held about 8 times the code of 2
+    assert code[16] < 2 * code[2], code

@@ -203,6 +203,16 @@ def test_seeded_id_escape(san):
     san.table(v, [v.to_physical(r.pages)], [])
 
 
+
+def test_seeded_write_collision(san):
+    """Two real decode lanes writing one block of one page (kv_write
+    would keep only one lane's token) are flagged; the same block of
+    another group, page or block is fine."""
+    with pytest.raises(ZensanViolation, match=r"write-collision"):
+        san.decode_writes([("a", "global", 3, 0), ("b", "global", 3, 0)])
+    san.decode_writes([("a", "global", 3, 0), ("b", "global", 3, 1),
+                       ("c", "global", 4, 0), ("d", "ring", 3, 0)])
+
 def test_seeded_view_leak(san):
     shared, views = _pod()
     v = views["a"]
