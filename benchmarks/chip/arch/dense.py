@@ -1,19 +1,22 @@
-"""The plain reference: a float32 forward pass of the configuration, and
-the comparison that decides ``correct``.
+"""The dense decoder: RMS norm, rotary position embedding (halves
+rotated), grouped-query causal attention, RMS norm, SiLU-gated MLP,
+residuals; then a final RMS norm and an untied head.  It is the
+published Mistral / Phi-3 decoder layer; ``arch/mistral.py`` and
+``arch/phi3.py`` take everything from here.
 
-It imports nothing of the program.  It draws its own weights from the
-seed, by the scheme the served model's weights are documented to follow
-(each leaf of the parameter tree, in the tree's sorted key order, from
-its own split of ``PRNGKey(seed)``: a float32 standard normal times 0.02,
-rounded to bfloat16; RMS-norm gains are stored as an offset from 1 and
-start at 0), and computes in float32 at ``highest`` matmul precision from
-those bfloat16 values, layer by layer and in blocks of query rows, so
-that it fits beside nothing else on the chip.
+The program's configuration: only decoder-only, RoPE, SiLU-gated,
+RMS-normed stacks of full attention are expressible; anything else is
+refused rather than run as something it is not.
 
-The layer is the published Mistral / Phi-3 decoder layer: RMS norm,
-rotary position embedding (halves rotated), grouped-query causal
-attention, RMS norm, SiLU-gated MLP, residuals; then a final RMS norm and
-an untied head.
+The plain reference imports nothing of the program.  It draws its own
+weights from the seed, by the scheme the served model's weights are
+documented to follow (each leaf of the parameter tree, in the tree's
+sorted key order, from its own split of ``PRNGKey(seed)``: a float32
+standard normal times 0.02, rounded to bfloat16; RMS-norm gains are
+stored as an offset from 1 and start at 0), and computes in float32 at
+``highest`` matmul precision from those bfloat16 values, layer by layer
+and in blocks of query rows, so that it fits beside nothing else on the
+chip.
 
 The comparison: run the reference once over each sampled prompt with its
 served tokens, and take the widest gap by which a served token's logit
@@ -23,12 +26,18 @@ place at the precision below the served one: every matmul's operands
 rounded to float8 (e4m3, per-tensor scale for weights, per-row for
 activations); its reading is the gap, under the float32 reference, of
 the token it puts first.
+
+The work a call needs, from its shapes: what a kernel or a step must
+compute and move whatever implements it.  Counts are of the algorithm,
+not of an implementation: padded batch lanes, masked page-table entries,
+the pool's relayout and recomputed values are not work.  A multiply-add
+is two operations.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +47,47 @@ STD = 0.02
 #: query rows per attention block
 Q_BLOCK = 512
 F8_MAX = 448.0
+#: bytes of one element as served (bfloat16 weights, KV and queries)
+BF16 = 2
+
+
+def model_config_kwargs(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``repro.configs.base.ModelConfig`` keyword arguments for a
+    configuration file written in Hugging Face ``config.json`` keys."""
+    act = config.get("hidden_act", "silu")
+    if act != "silu":
+        raise ValueError(f"{config['name']}: hidden_act {act!r} is not the "
+                         "program's gated SiLU MLP")
+    d = int(config["hidden_size"])
+    heads = int(config["num_attention_heads"])
+    return dict(
+        name=config["name"],
+        family="dense",
+        num_layers=int(config["num_hidden_layers"]),
+        d_model=d,
+        num_heads=heads,
+        num_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=int(config.get("head_dim") or d // heads),
+        d_ff=int(config["intermediate_size"]),
+        vocab_size=int(config["vocab_size"]),
+        rope_theta=float(config["rope_theta"]),
+        norm_eps=float(config["rms_norm_eps"]),
+        tie_embeddings=bool(config.get("tie_word_embeddings", False)),
+        max_context=int(config["max_position_embeddings"]),
+    )
+
+
+def numbers(cfg) -> Dict:
+    """The sizes a run records (``record["model"]``), from the program's
+    ``ModelConfig``: what the reference and the work counts read."""
+    return {"layers": cfg.num_layers, "d_model": cfg.d_model,
+            "heads": cfg.num_heads, "kv_heads": cfg.num_kv_heads,
+            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
+            "vocab": cfg.vocab_size, "norm_eps": cfg.norm_eps,
+            "rope_theta": cfg.rope_theta}
+
+
+# -- the reference -----------------------------------------------------
 
 
 def leaf_shapes(m: Dict) -> List[Tuple[str, Tuple[int, ...], float]]:
@@ -207,3 +257,61 @@ def served_gaps(w: Dict, m: Dict, prompt: Sequence[int],
             pick = low.argmax(axis=1)
             cgap = float(np.max(best - ref[np.arange(len(rows)), pick]))
     return gap, cgap
+
+
+# -- the work ----------------------------------------------------------
+
+
+def paged_attention(m: Dict, lens: Sequence[int]) -> Tuple[int, int]:
+    """(operations, bytes) of one decode step's paged-attention calls,
+    one a layer, over the real lanes whose valid lengths are ``lens``:
+    each lane's query against its live keys and values (scores and the
+    weighted sum, ``4 * len * heads * head_dim``), reading those keys and
+    values once and the query and output once."""
+    live = sum(int(n) for n in lens)
+    flops = 4 * live * m["heads"] * m["head_dim"]
+    byts = (live * m["kv_heads"] * m["head_dim"] * 2 * BF16
+            + len(lens) * m["heads"] * m["head_dim"] * 2 * BF16)
+    return flops * m["layers"], byts * m["layers"]
+
+
+#: each kernel's work in one decode step, by the kernel's name in the
+#: trace (``trace_reduce``: the function that makes its ``pallas_call``)
+KERNELS = {"paged_attention": paged_attention}
+
+
+def layer_matmul_flops(m: Dict) -> int:
+    """Operations of one token through one layer's weights: q, k, v and
+    output projections and the gated MLP's three matrices."""
+    d, h, kv, hd, ff = (m["d_model"], m["heads"], m["kv_heads"],
+                        m["head_dim"], m["d_ff"])
+    return 2 * d * (2 * h * hd + 2 * kv * hd) + 2 * 3 * d * ff
+
+
+def head_flops(m: Dict) -> int:
+    """Operations of one row of logits."""
+    return 2 * m["d_model"] * m["vocab"]
+
+
+def decode_flops(m: Dict, lens: Sequence[int]) -> int:
+    """Operations of one decode step over the real lanes with valid
+    lengths ``lens``: each lane's token through every layer, its
+    attention over its live context, and its row of logits."""
+    per_layer = sum(layer_matmul_flops(m) + 4 * int(n) * m["heads"]
+                    * m["head_dim"] for n in lens)
+    return per_layer * m["layers"] + len(lens) * head_flops(m)
+
+
+def prefill_flops(m: Dict, cached: int, total: int) -> int:
+    """Operations of prefilling prompt positions ``cached .. total - 1``
+    (the cached ones are not computed): each through every layer,
+    attending causally over all earlier positions, and one row of logits
+    for the last."""
+    n = total - cached
+    if n <= 0:
+        return 0
+    # sum over positions t of (t + 1) keys
+    keys = (total * (total + 1) - cached * (cached + 1)) // 2
+    per_layer = n * layer_matmul_flops(m) + 4 * keys * m["heads"] * m[
+        "head_dim"]
+    return per_layer * m["layers"] + head_flops(m)

@@ -3,17 +3,20 @@
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix;
 the benchmark's per-layer metrics are named there too.  Each lives in a
 file of its own under the benchmark directory, so a later change adds a
-cell, a mix or a metric by adding files and entries, never by editing
-code:
+cell, a mix, a metric or an architecture by adding files and entries,
+never by editing code:
 
 * ``configs/<config>.json`` -- the model as it is run (Hugging Face
   ``config.json`` keys) and the serve options of the deployment;
+* ``arch/<model_type>.py`` -- what is particular to the configuration's
+  architecture: the program's configuration, the plain reference and
+  the work counts;
 * ``traffic/<mix>.json`` -- the parameters of one traffic mix, whose
   ``kind`` names the generator module ``traffic/<kind>.py``;
 * ``metrics/<metric>.py`` -- the reader of one per-layer metric, a
   function ``read(record)`` that returns a number or None.
 
-Nothing here imports JAX or the program.
+Nothing here imports JAX or the program (an architecture module may).
 """
 
 from __future__ import annotations
@@ -24,7 +27,7 @@ import os
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
-#: the benchmark's own directory (configs/, traffic/, metrics/ live here)
+#: the benchmark's own directory (configs/, arch/, traffic/, metrics/ here)
 HERE = os.path.dirname(os.path.abspath(__file__))
 #: the checkout's root, where BENCHMARK.json lives
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -103,30 +106,16 @@ def metric_reader(name: str, *, bench_dir: Optional[str] = None
     return mod.read
 
 
-def model_config_kwargs(config: Dict[str, Any]) -> Dict[str, Any]:
-    """``repro.configs.base.ModelConfig`` keyword arguments for a
-    configuration file written in Hugging Face ``config.json`` keys.
-    Only decoder-only, RoPE, SiLU-gated, RMS-normed stacks of full
-    attention are expressible; anything else is refused here rather than
-    run as something it is not."""
-    act = config.get("hidden_act", "silu")
-    if act != "silu":
-        raise ValueError(f"{config['name']}: hidden_act {act!r} is not the "
-                         "program's gated SiLU MLP")
-    d = int(config["hidden_size"])
-    heads = int(config["num_attention_heads"])
-    return dict(
-        name=config["name"],
-        family="dense",
-        num_layers=int(config["num_hidden_layers"]),
-        d_model=d,
-        num_heads=heads,
-        num_kv_heads=int(config["num_key_value_heads"]),
-        head_dim=int(config.get("head_dim") or d // heads),
-        d_ff=int(config["intermediate_size"]),
-        vocab_size=int(config["vocab_size"]),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config.get("tie_word_embeddings", False)),
-        max_context=int(config["max_position_embeddings"]),
-    )
+def arch(config: Dict[str, Any], *, bench_dir: Optional[str] = None):
+    """The architecture module ``arch/<model_type>.py`` that the
+    configuration names by its own ``model_type`` key: the program's
+    configuration for it, its plain reference and its work counts
+    (``arch/__init__.py`` lists what each module gives)."""
+    bench_dir = bench_dir or HERE
+    kind = str(config.get("model_type"))
+    path = os.path.join(bench_dir, "arch", kind + ".py")
+    if not os.path.isfile(path):
+        raise KeyError(f"{config.get('name')}: no architecture module "
+                       f"{path} for model_type {kind!r}")
+    return _load_module(path, "chipbench_arch_" + kind.replace(".", "_")
+                        .replace("-", "_"))

@@ -8,7 +8,10 @@ asks for.  The cell, its configuration, its traffic mix and its metrics
 are found by name (``BENCHMARK.json``, ``configs/``, ``traffic/``,
 ``metrics/``).  A run has three parts:
 
-1. Set-up: the program draws its weights on the device from the seed;
+1. Set-up: the configuration's ``model_type`` picks the architecture
+   module (``arch/<model_type>.py``) that configures the program, holds
+   the plain reference and counts the work; the program draws its
+   weights on the device from the seed;
    throwaway requests, served through the same public path, take every
    program shape and prompt operation the traffic can reach, compiled or
    loaded from JAX's persistent cache (``JAX_COMPILATION_CACHE_DIR``
@@ -19,7 +22,7 @@ are found by name (``BENCHMARK.json``, ``configs/``, ``traffic/``,
    the repository's request tracer is on and the profiler traces the
    window's last stretch.
 3. The check and the result: a sample of the served requests is compared
-   with the plain float32 reference (``reference.py``) after the
+   with the architecture's plain float32 reference after the
    program's state is freed.  The last line of standard output is one
    JSON object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the
    cell's end-to-end metrics, or with ``--trace 1`` its per-layer ones),
@@ -128,14 +131,6 @@ def parse(argv: List[str]) -> argparse.Namespace:
     return ap.parse_args(argv)
 
 
-def model_numbers(cfg) -> Dict:
-    return {"layers": cfg.num_layers, "d_model": cfg.d_model,
-            "heads": cfg.num_heads, "kv_heads": cfg.num_kv_heads,
-            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
-            "vocab": cfg.vocab_size, "norm_eps": cfg.norm_eps,
-            "rope_theta": cfg.rope_theta}
-
-
 def obs_record(events, requests: Dict[str, Dict]) -> Dict:
     """The request tracer's events the per-layer metrics read."""
     admit, prefill, decode = {}, [], []
@@ -179,22 +174,21 @@ def sample(outputs: Dict, seed: int) -> List:
     return picked
 
 
-def check(outputs, tracked, picked, m, seed: int, control: bool,
-          limit: float) -> Dict:
-    """The comparison with the reference over the sample, each number
-    beside its limit.  With ``control`` the float8 control stands in the
-    program's place: the tokens judged are the ones it puts first."""
-    import reference
-    vocab = m["vocab"]
+def check(outputs, tracked, picked, arch, m: Dict, vocab: int, seed: int,
+          control: bool, limit: float) -> Dict:
+    """The comparison with the architecture's reference over the sample,
+    each number beside its limit.  With ``control`` the float8 control
+    stands in the program's place: the tokens judged are the ones it puts
+    first."""
     wrong_len = sum(1 for rid, (_, s, d) in outputs.items()
                     if d and len(s) != tracked[rid].spec.max_new + 1)
     outside = sum(1 for _, s, _ in outputs.values()
                   for t in s if not 0 <= t < vocab)
-    w = reference.weights(m, seed)
+    w = arch.weights(m, seed)
     gap, own, n = 0.0, 0.0, 0
     for rid in picked:
         prompt, served, _ = outputs[rid]
-        g, c = reference.served_gaps(w, m, prompt, served, control=control)
+        g, c = arch.served_gaps(w, m, prompt, served, control=control)
         gap, own, n = max(gap, c if control else g), max(own, g), \
             n + len(served)
     del w
@@ -260,8 +254,9 @@ def main(argv: Optional[List[str]] = None, *, require_tpu: bool = True,
 
     say(f"device platform={dev.platform} kind={dev.device_kind} "
         f"count={len(devices)}")
-    cfg = ModelConfig(**registry.model_config_kwargs(cell.config))
-    m = model_numbers(cfg)
+    arch = registry.arch(cell.config, bench_dir=bench_dir)
+    cfg = ModelConfig(**arch.model_config_kwargs(cell.config))
+    m = arch.numbers(cfg)
     opts = cell.config["serve"]
     program_seed = args.seed % (1 << 31)
     if mesh is None:
@@ -297,7 +292,9 @@ def main(argv: Optional[List[str]] = None, *, require_tpu: bool = True,
 
     c0, cs0 = counter.compiles, counter.compile_s
     pre0 = run.engine.stats.preempted
+    cpu0 = time.thread_time()
     run.window_loop(args.seconds, TRACE_SECONDS if args.trace else 0.0)
+    cpu = time.thread_time() - cpu0
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     in_window = counter.compiles - c0
     if tracer is not None:
@@ -309,7 +306,8 @@ def main(argv: Optional[List[str]] = None, *, require_tpu: bool = True,
         f"{(lat[-1] if lat else 0.0) * 1e3:.3f} ms over {len(lat)} "
         f"submissions; steps {len(run.steps)}; decode steps "
         f"{len(run.decodes)}; prefills {len(run.prefills)}; preemptions "
-        f"{run.engine.stats.preempted - pre0}")
+        f"{run.engine.stats.preempted - pre0}; {stalls(run)}; main "
+        f"thread's CPU time {cpu:.3f} s")
 
     requests = {}
     for rid, tr in run.tracked.items():
@@ -327,6 +325,7 @@ def main(argv: Optional[List[str]] = None, *, require_tpu: bool = True,
         say(f"request tracer dropped {tracer.dropped} events")
     if args.trace and run.trace_window is not None:
         record["trace"] = _reduce_trace(run, trace_dir)
+        add_work(record["trace"], arch, m)
     outputs = run.outputs()
     tracked = run.tracked
     attempted = sum(1 for r in record["requests"]
@@ -341,8 +340,8 @@ def main(argv: Optional[List[str]] = None, *, require_tpu: bool = True,
     say(f"device bytes in use after release: "
         f"{(dev.memory_stats() or {}).get('bytes_in_use')}")
     t_ref = time.perf_counter()
-    checks = check(outputs, tracked, picked, m, program_seed,
-                   bool(args.control),
+    checks = check(outputs, tracked, picked, arch, m, cfg.vocab_size,
+                   program_seed, bool(args.control),
                    float(cell.config["correct"]["max_logit_gap"]))
     checks["requests_failed"] = {"value": failed, "limit": 0}
     checks["compiles_in_window"] = {"value": in_window, "limit": 0}
@@ -374,6 +373,21 @@ def main(argv: Optional[List[str]] = None, *, require_tpu: bool = True,
     return 0
 
 
+def stalls(run) -> str:
+    """The window's longest step and longest time between two steps, each
+    with its offset from the window's start (the host's stalls)."""
+    t0 = run.window[0]
+    steps = [s for s in run.steps if s[0] >= t0]
+    if not steps:
+        return "no step"
+    a, b = max(((a, b) for a, b, *_ in steps), key=lambda s: s[1] - s[0])
+    gap, at = max(((s[0] - p[1], p[1]) for p, s in zip(steps, steps[1:])),
+                  default=(0.0, t0))
+    return (f"longest step {(b - a) * 1e3:.3f} ms at {a - t0:.3f} s, "
+            f"longest time between steps {gap * 1e3:.3f} ms at "
+            f"{at - t0:.3f} s")
+
+
 def _reduce_trace(run, trace_dir: str) -> Dict:
     """The traced window's device numbers, with the harness's record of
     the decode steps and prefills inside it."""
@@ -381,8 +395,8 @@ def _reduce_trace(run, trace_dir: str) -> Dict:
     t0, t1 = run.trace_window
     path = trace_reduce.find_trace(trace_dir)
     tr = trace_reduce.reduce(path) if path else {
-        "busy_s": 0.0, "ops": [], "kernel_s": 0.0, "kernel_calls": 0,
-        "spans": {}, "idle_gaps": []}
+        "busy_s": 0.0, "ops": [], "kernels": {}, "kernel_s": 0.0,
+        "kernel_calls": 0, "spans": {}, "idle_gaps": []}
     tr["window_s"] = t1 - t0
     tr["decode_lens"] = [lens for a, b, lens in run.decodes
                          if t0 <= a and b <= t1]
@@ -392,6 +406,25 @@ def _reduce_trace(run, trace_dir: str) -> Dict:
     tr["decode_s"] = spans.get("chipbench.decode", [0.0, 0])[0]
     tr["prefill_s"] = spans.get("chipbench.prefill", [0.0, 0])[0]
     return tr
+
+
+def add_work(tr: Dict, arch, m: Dict) -> None:
+    """The work of the traced steps, counted by the cell's architecture
+    module: ``decode_flops`` over the decode steps, ``prefill_flops``
+    over the prefills, and ``kernel_work[name] = [flops, bytes]`` over
+    the decode steps for each kernel the module names."""
+    tr["decode_flops"] = sum(arch.decode_flops(m, lens)
+                             for lens in tr["decode_lens"])
+    tr["prefill_flops"] = sum(arch.prefill_flops(m, cached, n)
+                              for n, cached in tr["prefills"])
+    tr["kernel_work"] = {}
+    for name, work in arch.KERNELS.items():
+        flops = byts = 0
+        for lens in tr["decode_lens"]:
+            f, b = work(m, lens)
+            flops += f
+            byts += b
+        tr["kernel_work"][name] = [flops, byts]
 
 
 if __name__ == "__main__":

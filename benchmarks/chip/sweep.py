@@ -55,7 +55,7 @@ def main() -> int:
                                              args.config + ".json"))
     traffic = registry.load_json(os.path.join(HERE, "traffic",
                                               args.traffic + ".json"))
-    cfg = ModelConfig(**registry.model_config_kwargs(config))
+    cfg = ModelConfig(**registry.arch(config).model_config_kwargs(config))
     opts = config["serve"]
     mesh = attached_mesh_spec()
     gen = registry.generator(traffic["kind"])

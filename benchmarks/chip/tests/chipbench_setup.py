@@ -22,7 +22,8 @@ for p in (BENCH, os.path.join(REPO, "src")):
 
 #: a tiny dense model: every width of the served architecture, shrunk
 TINY = {
-    "name": "tiny", "hidden_act": "silu", "hidden_size": 64,
+    "name": "tiny", "model_type": "mistral", "hidden_act": "silu",
+    "hidden_size": 64,
     "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 512, "max_position_embeddings": 4096,

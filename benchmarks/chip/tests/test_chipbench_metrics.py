@@ -6,7 +6,10 @@ import pytest
 
 import chipbench_setup  # noqa: F401  (the benchmark and src on the path)
 import registry
+import run
 import tails
+
+DENSE = registry.arch({"name": "t", "model_type": "mistral"})
 
 MODEL = {"layers": 2, "d_model": 64, "heads": 4, "kv_heads": 2,
          "head_dim": 16, "d_ff": 128, "vocab": 512}
@@ -21,6 +24,10 @@ def req(rid, due, stamps, prompt=100, cached=0, failed=False):
 @pytest.fixture
 def record():
     # window [10, 20], nominal end 20; times in seconds
+    trace = {"busy_s": 1.5, "window_s": 2.0, "kernel_s": 0.001,
+             "decode_lens": [[100, 300]], "decode_s": 0.5,
+             "prefill_s": 0.25, "prefills": [(200, 150)]}
+    run.add_work(trace, DENSE, MODEL)
     return {
         "window": (10.0, 20.0), "nominal_end": 20.0, "setup_s": 42.0,
         "memory_peak_bytes": 12_345_000_000, "model": MODEL,
@@ -45,9 +52,7 @@ def record():
                             {"t0": 10.1, "dur": 0.04, "batch": 4},
                             {"t0": 10.2, "dur": 0.03, "batch": 3}],
         },
-        "trace": {"busy_s": 1.5, "window_s": 2.0, "kernel_s": 0.001,
-                  "decode_lens": [[100, 300]], "decode_s": 0.5,
-                  "prefill_s": 0.25, "prefills": [(200, 150)]},
+        "trace": trace,
     }
 
 
@@ -103,14 +108,19 @@ def test_layer_metrics(record):
 
 
 def test_device_metrics_from_the_trace(record):
-    import kernel_work
-    f, b = kernel_work.paged_attention([100, 300], 4, 2, 16)
-    least = max(2 * f / 1e12, 2 * b / 1e9)
+    # the work the run counts with the cell's architecture module
+    f, b = DENSE.KERNELS["paged_attention"](MODEL, [100, 300])
+    assert f == 2 * 4 * 400 * 4 * 16
+    assert b == 2 * (400 * 2 * 16 * 2 * 2 + 2 * 4 * 16 * 2 * 2)
+    assert record["trace"]["kernel_work"] == {"paged_attention": [f, b]}
+    least = max(f / 1e12, b / 1e9)
     assert read("paged_attn_roofline", record) == pytest.approx(
         100 * least / 0.001)
-    d = kernel_work.decode_flops(MODEL, [100, 300])
+    d = DENSE.decode_flops(MODEL, [100, 300])
+    assert record["trace"]["decode_flops"] == d
     assert read("decode_mfu", record) == pytest.approx(100 * d / 0.5e12)
-    p = kernel_work.prefill_flops(MODEL, 150, 200)
+    p = DENSE.prefill_flops(MODEL, 150, 200)
+    assert record["trace"]["prefill_flops"] == p
     assert read("prefill_mfu", record) == pytest.approx(100 * p / 0.25e12)
 
 

@@ -3,6 +3,9 @@ metric added as new files, with no edit to a file already there."""
 
 import json
 import os
+import re
+
+import pytest
 
 from chipbench_setup import checkout  # noqa: F401  (a fixture)
 import registry
@@ -49,16 +52,27 @@ def test_new_files_are_found_by_name(checkout):
 
 
 def test_config_file_maps_to_the_program_config(checkout):
-    cell = registry.load_cell("tiny-chat", root=checkout,
-                              bench_dir=os.path.join(checkout, "benchmarks",
-                                                     "chip"))
-    kw = registry.model_config_kwargs(cell.config)
+    bench = os.path.join(checkout, "benchmarks", "chip")
+    cell = registry.load_cell("tiny-chat", root=checkout, bench_dir=bench)
+    dense = registry.arch(cell.config, bench_dir=bench)
+    kw = dense.model_config_kwargs(cell.config)
     assert kw["num_kv_heads"] == 2 and kw["head_dim"] == 16
     assert kw["norm_eps"] == 1e-5 and kw["family"] == "dense"
     bad = dict(cell.config, hidden_act="gelu")
-    try:
-        registry.model_config_kwargs(bad)
-    except ValueError:
-        pass
-    else:
-        raise AssertionError("a gelu MLP ran as the program's SiLU one")
+    with pytest.raises(ValueError, match="gelu"):
+        dense.model_config_kwargs(bad)
+
+
+def test_model_type_without_a_module_is_refused(checkout):
+    """A configuration whose ``model_type`` has no ``arch/`` module is
+    refused, naming the file it lacks; it never runs as the dense
+    decoder, and neither does one that names no type."""
+    bench = os.path.join(checkout, "benchmarks", "chip")
+    cell = registry.load_cell("tiny-chat", root=checkout, bench_dir=bench)
+    latent = dict(cell.config, model_type="deepseek_v2")
+    want = os.path.join(bench, "arch", "deepseek_v2.py")
+    with pytest.raises(KeyError, match=re.escape(want)):
+        registry.arch(latent, bench_dir=bench)
+    untyped = {k: v for k, v in cell.config.items() if k != "model_type"}
+    with pytest.raises(KeyError, match="None.py"):
+        registry.arch(untyped, bench_dir=bench)
